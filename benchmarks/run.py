@@ -56,6 +56,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.full and args.quick:
         ap.error("--full and --quick are mutually exclusive")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.time()
 
     from benchmarks import gateway_throughput, kernel_bench

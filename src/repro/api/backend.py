@@ -363,12 +363,6 @@ class MeshSpillBackend(_BackendBase):
         super().__init__(spec)
         from repro.comanager.dataplane import MeshSpillExecutor
 
-        if mesh is None:
-            # match ShardedBackend: spill onto ALL local devices by default
-            # (MeshSpillExecutor's own fallback is the degenerate 1x1 mesh).
-            from repro.launch.mesh import make_data_mesh
-
-            mesh = make_data_mesh()
         self.executor = MeshSpillExecutor(mesh, axis)
         self._n_shards = self.executor.mesh.shape[axis]
 

@@ -34,21 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map landed after 0.4.x (where it lives in experimental); the
-# "skip varying-across-mesh checks" kwarg was renamed check_rep -> check_vma
-# at a different point, so detect the kwarg itself, not just the symbol.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-import inspect as _inspect
-
-_SM_SKIP_CHECKS = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
-
 from repro.api.capabilities import declare
 from repro.core import shift_rule
 from repro.core.sim import CircuitSpec
@@ -283,14 +268,14 @@ def sharded_executor(spec: CircuitSpec, mesh: Mesh, axis: str = "data"):
     def _local(theta, data):
         return kops.vqc_fidelity(spec, theta, data)
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None)),
         out_specs=P(axis),
-        # the Pallas interpret-mode call inside produces ShapeDtypeStructs
-        # without vma annotations; skip the varying-across-mesh check.
-        **_SM_SKIP_CHECKS,
+        # the Pallas call inside produces ShapeDtypeStructs without vma
+        # annotations; skip the varying-across-mesh check.
+        check_vma=False,
     )
 
     shift_fns: dict[bool, Callable] = {}
@@ -301,12 +286,12 @@ def sharded_executor(spec: CircuitSpec, mesh: Mesh, axis: str = "data"):
             def _local_shift(theta, data):
                 return kops.vqc_fidelity_shiftgroups(spec, theta, data, four_term)
 
-            shift_fns[four_term] = _shard_map(
+            shift_fns[four_term] = jax.shard_map(
                 _local_shift,
                 mesh=mesh,
                 in_specs=(P(axis, None), P(axis, None)),
                 out_specs=P(None, axis),
-                **_SM_SKIP_CHECKS,
+                check_vma=False,
             )
         return shift_fns[four_term]
 
@@ -321,12 +306,12 @@ def sharded_executor(spec: CircuitSpec, mesh: Mesh, axis: str = "data"):
                     spec, theta, data, four_term, groups
                 )
 
-            group_fns[key] = _shard_map(
+            group_fns[key] = jax.shard_map(
                 _local_groups,
                 mesh=mesh,
                 in_specs=(P(axis, None), P(axis, None)),
                 out_specs=P(None, axis),
-                **_SM_SKIP_CHECKS,
+                check_vma=False,
             )
         return group_fns[key]
 
@@ -383,9 +368,9 @@ class MeshSpillExecutor:
 
     def __init__(self, mesh: Mesh | None = None, axis: str = "data"):
         if mesh is None:
-            from repro.launch.mesh import make_host_mesh
+            from repro.launch.mesh import make_data_mesh
 
-            mesh = make_host_mesh()
+            mesh = make_data_mesh()  # every local device on ``data``
         self.mesh = mesh
         self.axis = axis
         self._per_spec: dict[CircuitSpec, Callable] = {}

@@ -15,9 +15,11 @@ Python, the angles are traced.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
 import jax.numpy as jnp
+from jax.lax import Precision
 
 from repro.core import gates as G
 
@@ -51,13 +53,13 @@ def apply_gate(state: State, u: G.Mat, qubits: Sequence[int], n_qubits: int) -> 
     t_im = jnp.transpose(t[1], perm).reshape(batch + (2**k, -1))
 
     u_re, u_im = u
-    # complex matmul: (U_re + i U_im) @ (t_re + i t_im)
-    o_re = jnp.einsum("ij,...jk->...ik", u_re, t_re) - jnp.einsum(
-        "ij,...jk->...ik", u_im, t_im
+    # complex matmul: (U_re + i U_im) @ (t_re + i t_im).  HIGHEST keeps the
+    # reference exact in f32 on TPU, where the default is one bf16 pass.
+    mm = functools.partial(
+        jnp.einsum, "ij,...jk->...ik", precision=Precision.HIGHEST
     )
-    o_im = jnp.einsum("ij,...jk->...ik", u_re, t_im) + jnp.einsum(
-        "ij,...jk->...ik", u_im, t_re
-    )
+    o_re = mm(u_re, t_re) - mm(u_im, t_im)
+    o_im = mm(u_re, t_im) + mm(u_im, t_re)
 
     o_re = o_re.reshape(batch + (2,) * n_qubits)
     o_im = o_im.reshape(batch + (2,) * n_qubits)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.vqc_statevector import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -113,8 +115,7 @@ def flash_attention(
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
     n_q = s // block_q
     n_k = s // block_k
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
 
     kernel = functools.partial(
         _flash_kernel,

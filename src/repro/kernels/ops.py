@@ -1,7 +1,7 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 ``interpret`` defaults to auto: real Mosaic lowering on TPU, interpret mode
-on CPU (this container).  The wrappers are the executor used by the
+on the CPU backend, an error on any other (``K.resolve_interpret``).  The wrappers are the executor used by the
 co-Manager data plane and by ``shift_rule`` when kernel execution is on.
 """
 from __future__ import annotations

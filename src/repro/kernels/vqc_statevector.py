@@ -20,14 +20,15 @@ circuit — encoding rotations, variational layers, SWAP test, ancilla readout
     gate; fusion removes all of it (see benchmarks/kernel_bench.py).
 
 VMEM budget: state tile is 2 * 4 * 2**n * TB bytes — for the paper's 7-qubit
-circuits and TB=512 that is 512 KB, far under the ~16 MB/core VMEM of a
-TPU v5e.  Qubit counts up to ~12 fit comfortably (2 * 4 * 4096 * 128 = 4 MB
+circuits and TB=512 that is 512 KB, far under the 16 MiB of VMEM a v5e
+kernel may use by default.  Qubit counts up to ~12 fit comfortably (2 * 4 * 4096 * 128 = 4 MB
 at TB=128); beyond that, shrink TB.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -49,143 +50,128 @@ def kernel_tb(n_lanes: int, tb: int = 4 * LANES) -> int:
 
 
 # ----------------------------------------------------------- gate micro-ops
-# Each helper operates on (re, im) arrays of shape (2**n, TB) and per-lane
-# angle vectors of shape (TB,).  Qubit q is the q-th MOST significant bit of
-# the basis (row) index, matching repro.core.sim.
+# Each helper operates on (re, im) arrays of shape (rows, TB) and per-lane
+# cos/sin vectors of shape (1, TB).  Qubit q is the q-th MOST significant bit
+# of the basis (row) index, matching repro.core.sim.
+#
+# Every gate is written in the three operations Mosaic lowers on any f32
+# tile: static sublane rotations (``pltpu.roll``), ``broadcasted_iota`` row
+# masks and selects, and elementwise arithmetic.  A one-qubit gate pairs
+# row r with its partner r ^ 2**b (b = n-1-q, the qubit's bit); the partner
+# tile is one of two rotations of the state by 2**b rows, chosen per row by
+# the bit.  No reshape, stack or scatter touches the sublane axis.
+
+#: f32 sublanes per vreg: state tiles are padded up to a multiple of this.
+SUBLANES = 8
 
 
-def _split1(x: jnp.ndarray, q: int, n: int):
-    """-> (x0, x1) halves along qubit q's bit; each (2**q, 2**(n-q-1), TB)."""
-    tb = x.shape[-1]
-    t = x.reshape(2**q, 2, 2 ** (n - q - 1), tb)
-    return t[:, 0], t[:, 1]
+def state_rows(n: int) -> int:
+    """Sublane rows of an n-qubit state tile: 2**n padded up to one full
+    f32 vreg tile, so every slice, DMA and reduction starts on a sublane
+    boundary.  Padding rows start at zero and a gate only mixes a row with
+    partners inside its own padding block, so they stay zero."""
+    return max(2**n, SUBLANES)
 
 
-def _merge1(x0, x1, q: int, n: int, tb: int):
-    t = jnp.stack([x0, x1], axis=1)
-    return t.reshape(2**n, tb)
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` -> compiled Mosaic on TPU, the Pallas interpreter
+    on the CPU backend, and an error anywhere else (a GPU or unknown
+    backend would otherwise run the interpreter silently)."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"backend {backend!r} is neither"
+    )
+
+
+def _bit(x, q: int, n: int):
+    """Row mask of x's shape: qubit q of the row's basis index is 1."""
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return (row & (1 << (n - 1 - q))) != 0
+
+
+def _flip(x, q: int, n: int):
+    """x with every row r replaced by row r ^ 2**(n-1-q) (qubit q flipped)."""
+    stride = 1 << (n - 1 - q)
+    down = pltpu.roll(x, stride, 0)  # row r <- row r - stride
+    up = pltpu.roll(x, x.shape[0] - stride, 0)  # row r <- row r + stride
+    return jnp.where(_bit(x, q, n), down, up)
 
 
 def _rot1(re, im, q, n, c, s, kind):
     """Apply RX/RY/RZ with per-lane cos/sin (c, s) on qubit q."""
-    tb = re.shape[-1]
-    r0, r1 = _split1(re, q, n)
-    i0, i1 = _split1(im, q, n)
     if kind == "ry":  # [[c,-s],[s,c]] real
-        nr0, ni0 = c * r0 - s * r1, c * i0 - s * i1
-        nr1, ni1 = s * r0 + c * r1, s * i0 + c * i1
-    elif kind == "rx":  # [[c,-is],[-is,c]]
-        nr0, ni0 = c * r0 + s * i1, c * i0 - s * r1
-        nr1, ni1 = c * r1 + s * i0, c * i1 - s * r0
-    elif kind == "rz":  # diag(e^{-it/2}, e^{it/2})
-        nr0, ni0 = c * r0 + s * i0, c * i0 - s * r0
-        nr1, ni1 = c * r1 - s * i1, c * i1 + s * r1
-    else:
-        raise ValueError(kind)
-    return (_merge1(nr0, nr1, q, n, tb), _merge1(ni0, ni1, q, n, tb))
-
-
-def _split2(x, qa, qb, n):
-    """-> 2x2 blocks b[ba][bb] over qubits qa < qb; each block
-    (2**qa, 2**(qb-qa-1), 2**(n-qb-1), TB)."""
-    tb = x.shape[-1]
-    t = x.reshape(2**qa, 2, 2 ** (qb - qa - 1), 2, 2 ** (n - qb - 1), tb)
-    return ((t[:, 0, :, 0], t[:, 0, :, 1]), (t[:, 1, :, 0], t[:, 1, :, 1]))
-
-
-def _merge2(b, qa, qb, n, tb):
-    t = jnp.stack(
-        [
-            jnp.stack([b[0][0], b[0][1]], axis=2),
-            jnp.stack([b[1][0], b[1][1]], axis=2),
-        ],
-        axis=1,
-    )
-    return t.reshape(2**n, tb)
+        sg = jnp.where(_bit(re, q, n), s, -s)
+        return c * re + sg * _flip(re, q, n), c * im + sg * _flip(im, q, n)
+    if kind == "rx":  # [[c,-is],[-is,c]]
+        return c * re + s * _flip(im, q, n), c * im - s * _flip(re, q, n)
+    if kind == "rz":  # diag(e^{-it/2}, e^{it/2})
+        sg = jnp.where(_bit(re, q, n), -s, s)
+        return c * re + sg * im, c * im - sg * re
+    raise ValueError(kind)
 
 
 def _rot2(re, im, qa, qb, n, c, s, kind):
     """RYY / RZZ / CRY / CRZ with per-lane (c, s); qa < qb required."""
-    tb = re.shape[-1]
-    R = _split2(re, qa, qb, n)
-    I = _split2(im, qa, qb, n)  # noqa: E741
-    r00, r01, r10, r11 = R[0][0], R[0][1], R[1][0], R[1][1]
-    i00, i01, i10, i11 = I[0][0], I[0][1], I[1][0], I[1][1]
-    if kind == "rzz":  # diag phases: e^{-it/2} on |00>,|11>; e^{+it/2} on |01>,|10>
-        nr00, ni00 = c * r00 + s * i00, c * i00 - s * r00
-        nr11, ni11 = c * r11 + s * i11, c * i11 - s * r11
-        nr01, ni01 = c * r01 - s * i01, c * i01 + s * r01
-        nr10, ni10 = c * r10 - s * i10, c * i10 + s * r10
-    elif kind == "ryy":  # couples (00,11) with +i s, (01,10) with -i s
-        nr00, ni00 = c * r00 - s * i11, c * i00 + s * r11
-        nr11, ni11 = c * r11 - s * i00, c * i11 + s * r00
-        nr01, ni01 = c * r01 + s * i10, c * i01 - s * r10
-        nr10, ni10 = c * r10 + s * i01, c * i10 - s * r01
-    elif kind == "cry":  # RY on qb within qa=1 block
-        nr00, ni00, nr01, ni01 = r00, i00, r01, i01
-        nr10, ni10 = c * r10 - s * r11, c * i10 - s * i11
-        nr11, ni11 = s * r10 + c * r11, s * i10 + c * i11
-    elif kind == "crz":  # RZ on qb within qa=1 block
-        nr00, ni00, nr01, ni01 = r00, i00, r01, i01
-        nr10, ni10 = c * r10 + s * i10, c * i10 - s * r10
-        nr11, ni11 = c * r11 - s * i11, c * i11 + s * r11
-    else:
-        raise ValueError(kind)
-    return (
-        _merge2(((nr00, nr01), (nr10, nr11)), qa, qb, n, tb),
-        _merge2(((ni00, ni01), (ni10, ni11)), qa, qb, n, tb),
-    )
+    if kind == "rzz":  # e^{-it/2} on |00>,|11>; e^{+it/2} on |01>,|10>
+        sg = jnp.where(_bit(re, qa, n) != _bit(re, qb, n), -s, s)
+        return c * re + sg * im, c * im - sg * re
+    if kind == "ryy":  # couples (00,11) with +i s, (01,10) with -i s
+        sg = jnp.where(_bit(re, qa, n) != _bit(re, qb, n), s, -s)
+        p_re = _flip(_flip(re, qa, n), qb, n)
+        p_im = _flip(_flip(im, qa, n), qb, n)
+        return c * re + sg * p_im, c * im - sg * p_re
+    if kind in ("cry", "crz"):  # RY / RZ on qb within the qa=1 block
+        nre, nim = _rot1(re, im, qb, n, c, s, kind[1:])
+        ctrl = _bit(re, qa, n)
+        return jnp.where(ctrl, nre, re), jnp.where(ctrl, nim, im)
+    raise ValueError(kind)
 
 
 def _h(re, im, q, n):
-    tb = re.shape[-1]
     inv = 0.7071067811865476
-    r0, r1 = _split1(re, q, n)
-    i0, i1 = _split1(im, q, n)
+    b = _bit(re, q, n)
     return (
-        _merge1((r0 + r1) * inv, (r0 - r1) * inv, q, n, tb),
-        _merge1((i0 + i1) * inv, (i0 - i1) * inv, q, n, tb),
+        (_flip(re, q, n) + jnp.where(b, -re, re)) * inv,
+        (_flip(im, q, n) + jnp.where(b, -im, im)) * inv,
     )
-
-
-def _split3(x, qa, qb, qc_, n):
-    tb = x.shape[-1]
-    t = x.reshape(
-        2**qa, 2, 2 ** (qb - qa - 1), 2, 2 ** (qc_ - qb - 1), 2, 2 ** (n - qc_ - 1), tb
-    )
-    return t
 
 
 def _cswap(re, im, qa, qb, qc_, n):
     """Fredkin: control qa, swap qb<->qc_ (qa < qb < qc_)."""
-    tb = re.shape[-1]
-    outs = []
-    for x in (re, im):
-        t = _split3(x, qa, qb, qc_, n)
-        # within control=1 block, swap the (qb, qc_) bit pair (0,1)<->(1,0)
-        a01 = t[:, 1, :, 0, :, 1]
-        a10 = t[:, 1, :, 1, :, 0]
-        t = t.at[:, 1, :, 0, :, 1].set(a10).at[:, 1, :, 1, :, 0].set(a01)
-        outs.append(t.reshape(2**n, tb))
-    return outs[0], outs[1]
+    sel = _bit(re, qa, n) & (_bit(re, qb, n) != _bit(re, qc_, n))
+    return tuple(
+        jnp.where(sel, _flip(_flip(x, qb, n), qc_, n), x) for x in (re, im)
+    )
 
 
-def _op_angle(op, theta_blk, data_blk, delta: float = 0.0):
-    """Per-lane angle vector for a parameterized op (+ static shift delta)."""
+def _op_cos_sin(op, theta_ref, data_ref, delta: float = 0.0, invert: bool = False):
+    """Per-lane (cos, sin) of half the op's angle (+ static shift delta,
+    negated when inverting): (1, TB) rows read straight from the angle
+    refs, or Python floats for constant angles."""
     kind, j = op.param
+    sign = -1.0 if invert else 1.0  # rotation: g(t)^dagger = g(-t)
+    if kind == "const":
+        half = sign * (float(j) + delta) / 2
+        return math.cos(half), math.sin(half)
     if kind == "theta":
-        ang = theta_blk[j]
+        ang = theta_ref[j : j + 1, :]
     elif kind == "data":
-        ang = data_blk[j]
-    elif kind == "const":
-        ang = jnp.asarray(j, jnp.float32)
+        ang = data_ref[j : j + 1, :]
     else:
         raise ValueError(op.param)
-    return ang + delta if delta else ang
+    half = (ang + delta) * (sign / 2) if delta else ang * (sign / 2)
+    return jnp.cos(half), jnp.sin(half)
 
 
 def _apply_one(
-    op, re, im, n, theta_blk, data_blk, delta: float = 0.0, invert: bool = False
+    op, re, im, n, theta_ref, data_ref, delta: float = 0.0, invert: bool = False
 ):
     """Apply one gate (optionally angle-shifted by ``delta`` or inverted)."""
     if op.gate == "h":
@@ -193,11 +179,8 @@ def _apply_one(
     if op.gate == "cswap":
         qa, qb, qc_ = op.qubits
         return _cswap(re, im, qa, qb, qc_, n)  # self-inverse
-    ang = _op_angle(op, theta_blk, data_blk, delta)
-    if invert:  # rotation: g(t)^dagger = g(-t)
-        ang = -ang
-    c, s = jnp.cos(ang / 2), jnp.sin(ang / 2)
     if op.gate in ("rx", "ry", "rz"):
+        c, s = _op_cos_sin(op, theta_ref, data_ref, delta, invert)
         return _rot1(re, im, op.qubits[0], n, c, s, op.gate)
     if op.gate in ("ryy", "rzz", "cry", "crz"):
         qa, qb = op.qubits
@@ -208,42 +191,57 @@ def _apply_one(
                 raise NotImplementedError(
                     f"{op.gate} requires ascending (control, target) qubits"
                 )
+        c, s = _op_cos_sin(op, theta_ref, data_ref, delta, invert)
         return _rot2(re, im, qa, qb, n, c, s, op.gate)
     raise NotImplementedError(op.gate)
 
 
-def _apply_ops(spec: CircuitSpec, re, im, theta_blk, data_blk):
-    """Unrolled gate sequence on a (dim, TB) tile. theta_blk: (P, TB)."""
-    n = spec.n_qubits
-    for op in spec.ops:
-        re, im = _apply_one(op, re, im, n, theta_blk, data_blk)
+def _apply_ops(n: int, ops, re, im, theta_ref, data_ref):
+    """Unrolled gate sequence on a (rows, TB) tile."""
+    for op in ops:
+        re, im = _apply_one(op, re, im, n, theta_ref, data_ref)
     return re, im
+
+
+def _row_sum(x):
+    """(rows, TB) -> (1, TB) column sums in a fixed association order:
+    aligned halvings down to one vreg tile, then a rotate-and-add
+    butterfly over its 8 sublanes.  A plain ``sum(axis=0)`` lets the CPU
+    interpreter pick the order per launch shape, so the same lane could
+    round differently when packed into a wider launch."""
+    rows = x.shape[0]
+    while rows > SUBLANES:
+        rows //= 2
+        x = x[:rows] + x[rows:]
+    shift = SUBLANES // 2
+    while shift:
+        x = x + pltpu.roll(x, shift, 0)
+        shift //= 2
+    return x[0:1]
+
+
+def _zero_tile(n: int, tb: int):
+    """|0...0> batch of n-qubit states, built in VREGs — never read from HBM."""
+    shape = (state_rows(n), tb)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    re = jnp.where(row == 0, 1.0, 0.0).astype(jnp.float32)
+    return re, jnp.zeros(shape, jnp.float32)
 
 
 # ------------------------------------------------------------------ kernels
 def _fidelity_kernel(spec: CircuitSpec, theta_ref, data_ref, p0_ref):
-    tb = theta_ref.shape[-1]
-    dim = 2**spec.n_qubits
-    # |0...0> batch, built in VREGs — never read from HBM.
-    row = jax.lax.broadcasted_iota(jnp.int32, (dim, tb), 0)
-    re = jnp.where(row == 0, 1.0, 0.0).astype(jnp.float32)
-    im = jnp.zeros((dim, tb), jnp.float32)
-    re, im = _apply_ops(spec, re, im, theta_ref[...], data_ref[...])
+    n = spec.n_qubits
+    re, im = _zero_tile(n, theta_ref.shape[-1])
+    re, im = _apply_ops(n, spec.ops, re, im, theta_ref, data_ref)
     prob = re * re + im * im
-    half = jax.lax.broadcasted_iota(jnp.int32, (dim, tb), 0) < (dim // 2)
-    p0 = jnp.where(half, prob, 0.0).sum(axis=0, keepdims=True)  # ancilla = MSB
-    p0_ref[...] = p0
+    half = jax.lax.broadcasted_iota(jnp.int32, prob.shape, 0) < (2**n // 2)
+    p0_ref[...] = _row_sum(jnp.where(half, prob, 0.0))  # ancilla = MSB
 
 
 def _state_kernel(spec: CircuitSpec, theta_ref, data_ref, re_ref, im_ref):
-    tb = theta_ref.shape[-1]
-    dim = 2**spec.n_qubits
-    row = jax.lax.broadcasted_iota(jnp.int32, (dim, tb), 0)
-    re = jnp.where(row == 0, 1.0, 0.0).astype(jnp.float32)
-    im = jnp.zeros((dim, tb), jnp.float32)
-    re, im = _apply_ops(spec, re, im, theta_ref[...], data_ref[...])
-    re_ref[...] = re
-    im_ref[...] = im
+    n = spec.n_qubits
+    re, im = _zero_tile(n, theta_ref.shape[-1])
+    re_ref[...], im_ref[...] = _apply_ops(n, spec.ops, re, im, theta_ref, data_ref)
 
 
 def _grid_call(
@@ -252,15 +250,15 @@ def _grid_call(
     """theta_t: (P, C), data_t: (D, C) with C % tb == 0."""
     p, c = theta_t.shape
     d = data_t.shape[0]
-    dim = 2**spec.n_qubits
+    rows = state_rows(spec.n_qubits)
     grid = (c // tb,)
     in_specs = [
         pl.BlockSpec((p, tb), lambda i: (0, i)),
         pl.BlockSpec((d, tb), lambda i: (0, i)),
     ]
     if want_state:
-        out_shape = [jax.ShapeDtypeStruct((dim, c), jnp.float32)] * 2
-        out_specs = [pl.BlockSpec((dim, tb), lambda i: (0, i))] * 2
+        out_shape = [jax.ShapeDtypeStruct((rows, c), jnp.float32)] * 2
+        out_specs = [pl.BlockSpec((rows, tb), lambda i: (0, i))] * 2
         kern = functools.partial(_state_kernel, spec)
     else:
         out_shape = jax.ShapeDtypeStruct((1, c), jnp.float32)
@@ -268,12 +266,29 @@ def _grid_call(
         kern = functools.partial(_fidelity_kernel, spec)
     return pl.pallas_call(
         kern,
+        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
     )(theta_t, data_t)
+
+
+def _block_lanes(tb: int, interpret: bool) -> int:
+    """Lane width of one grid block: ``tb`` compiled, LANES interpreted.
+    XLA:CPU compiles the interpreted block body per block shape (fusion,
+    FMA contraction), so a lane comes out bit-identical whether it is
+    launched alone or packed with other banks only if every launch uses
+    the same block width."""
+    return LANES if interpret else tb
+
+
+def _lane_major(x: jnp.ndarray, tb: int) -> jnp.ndarray:
+    """(C, K) per-circuit angles -> (K, C + pad) lane-major f32, C padded
+    up to a multiple of the lane tile."""
+    pad = (-x.shape[0]) % tb
+    return jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0))).T
 
 
 def vqc_p0(
@@ -284,14 +299,17 @@ def vqc_p0(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Batched ancilla-P0 for a circuit bank. theta: (C,P), data: (C,D) -> (C,)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     c = theta.shape[0]
-    tb = kernel_tb(c, tb)
-    pad = (-c) % tb
-    theta_t = jnp.pad(theta, ((0, pad), (0, 0))).T
-    data_t = jnp.pad(data, ((0, pad), (0, 0))).T
-    p0 = _grid_call(spec, theta_t, data_t, tb, interpret, want_state=False)
+    interpret = resolve_interpret(interpret)
+    tb = _block_lanes(kernel_tb(c, tb), interpret)
+    p0 = _grid_call(
+        spec,
+        _lane_major(theta, tb),
+        _lane_major(data, tb),
+        tb,
+        interpret,
+        want_state=False,
+    )
     return p0[0, :c]
 
 
@@ -303,15 +321,19 @@ def vqc_state(
     interpret: bool | None = None,
 ):
     """Batched final statevector (re, im), each (C, 2**n) — for kernel tests."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     c = theta.shape[0]
-    tb = kernel_tb(c, tb)
-    pad = (-c) % tb
-    theta_t = jnp.pad(theta, ((0, pad), (0, 0))).T
-    data_t = jnp.pad(data, ((0, pad), (0, 0))).T
-    re, im = _grid_call(spec, theta_t, data_t, tb, interpret, want_state=True)
-    return re[:, :c].T, im[:, :c].T
+    interpret = resolve_interpret(interpret)
+    tb = _block_lanes(kernel_tb(c, tb), interpret)
+    re, im = _grid_call(
+        spec,
+        _lane_major(theta, tb),
+        _lane_major(data, tb),
+        tb,
+        interpret,
+        want_state=True,
+    )
+    dim = 2**spec.n_qubits
+    return re[:dim, :c].T, im[:dim, :c].T
 
 
 # ----------------------------------------------- shift-structured execution
@@ -465,19 +487,13 @@ def build_shift_plan(spec: CircuitSpec) -> ShiftPlan | None:
     )
 
 
-def _zero_tile(dim: int, tb: int):
-    row = jax.lax.broadcasted_iota(jnp.int32, (dim, tb), 0)
-    re = jnp.where(row == 0, 1.0, 0.0).astype(jnp.float32)
-    im = jnp.zeros((dim, tb), jnp.float32)
-    return re, im
-
-
 def _inner_fidelity(chi, phi):
-    """|<chi|phi>|^2 per lane; chi/phi are (re, im) pairs of (dim, TB)."""
+    """|<chi|phi>|^2 per lane, (1, TB); chi/phi are (re, im) pairs of
+    (rows, TB) — whole aligned tiles, padding rows included (all zero)."""
     cre, cim = chi
     pre, pim = phi
-    ip_re = (cre * pre + cim * pim).sum(axis=0)
-    ip_im = (cre * pim - cim * pre).sum(axis=0)
+    ip_re = _row_sum(cre * pre + cim * pim)
+    ip_im = _row_sum(cre * pim - cim * pre)
     return ip_re * ip_re + ip_im * ip_im
 
 
@@ -503,7 +519,7 @@ def _collect_variants(plan: ShiftPlan, shifts, groups, n_params: int):
     return variants
 
 
-def _replay_variant(plan: ShiftPlan, j: int, s: float, state, theta_blk, data_blk):
+def _replay_variant(plan: ShiftPlan, j: int, s: float, state, theta_ref, data_ref):
     """Suffix replay for one shift variant: apply parameter j's dependent
     span of train ops to its checkpoint ``state``, the shift ``s`` added to
     every gate the parameter drives.  Single-use parameters degenerate to
@@ -513,7 +529,7 @@ def _replay_variant(plan: ShiftPlan, j: int, s: float, state, theta_blk, data_bl
     for k in range(first, last + 1):
         op = plan.train_ops[k]
         delta = s if op.param == ("theta", j) else 0.0
-        re, im = _apply_one(op, re, im, plan.m, theta_blk, data_blk, delta=delta)
+        re, im = _apply_one(op, re, im, plan.m, theta_ref, data_ref, delta=delta)
     return re, im
 
 
@@ -526,14 +542,10 @@ def _shiftbank_kernel(
     1 + s*P + j is shift s of param j (bank order).
     """
     tb = theta_ref.shape[-1]
-    dim = 2**plan.m
-    theta_blk = theta_ref[...]
-    data_blk = data_ref[...]
 
     # 1. data register: one theta-independent pass, shared by every variant.
-    d_re, d_im = _zero_tile(dim, tb)
-    for op in plan.data_ops:
-        d_re, d_im = _apply_one(op, d_re, d_im, plan.m, theta_blk, data_blk)
+    d_re, d_im = _zero_tile(plan.m, tb)
+    d_re, d_im = _apply_ops(plan.m, plan.data_ops, d_re, d_im, theta_ref, data_ref)
 
     wanted = set(groups)
     variants = _collect_variants(plan, shifts, groups, n_params)
@@ -545,11 +557,11 @@ def _shiftbank_kernel(
     # 2. forward pass with base angles, checkpointing the prefix before each
     #    anchored parameter's FIRST dependent gate.
     checkpoints = {}
-    t_re, t_im = _zero_tile(dim, tb)
+    t_re, t_im = _zero_tile(plan.m, tb)
     for k, op in enumerate(plan.train_ops):
         if k in firsts:
             checkpoints[k] = (t_re, t_im)
-        t_re, t_im = _apply_one(op, t_re, t_im, plan.m, theta_blk, data_blk)
+        t_re, t_im = _apply_one(op, t_re, t_im, plan.m, theta_ref, data_ref)
 
     rows = {}
     f0 = _inner_fidelity((d_re, d_im), (t_re, t_im))
@@ -568,14 +580,15 @@ def _shiftbank_kernel(
         for g, j, s in variants.get(k, ()):
             first = plan.theta_positions[j][0]
             v_re, v_im = _replay_variant(
-                plan, j, s, checkpoints[first], theta_blk, data_blk
+                plan, j, s, checkpoints[first], theta_ref, data_ref
             )
             rows[g] = _inner_fidelity((c_re, c_im), (v_re, v_im))
         if k > lowest:
             c_re, c_im = _apply_one(
-                op, c_re, c_im, plan.m, theta_blk, data_blk, invert=True
+                op, c_re, c_im, plan.m, theta_ref, data_ref, invert=True
             )
-    out_ref[...] = jnp.stack([rows[g] for g in groups], axis=0)
+    for i, g in enumerate(groups):  # one single-sublane store per row
+        out_ref[i : i + 1, :] = rows[g]
 
 
 # --------------------------------------- VMEM-aware checkpoint spilling
@@ -605,14 +618,21 @@ def _shiftbank_kernel(
 #: for the angle blocks, the running states, and double buffering.
 VMEM_BUDGET_BYTES = 14 * 1024 * 1024
 
+#: scoped VMEM each launch may use.  The budget above models the live
+#: register states; gate application adds compiler temporaries (rotated
+#: copies and row masks of the widest state) on top, which at m=8, TB=512
+#: overflow the compiler's default 16 MiB scope.  A v5e core has 128 MiB.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
 #: live non-checkpoint states a tile launch holds (running state, chi,
 #: boundary, one shifted variant) — reserved out of the budget.
 _RESERVED_STATES = 4
 
 
 def _state_bytes(m: int, tb: int) -> int:
-    """Bytes of one (re, im) register state tile."""
-    return 2 * 4 * (2**m) * tb
+    """Bytes of one (re, im) register state tile (sublane-padded rows)."""
+    return 2 * 4 * state_rows(m) * tb
 
 
 def checkpoint_vmem_bytes(plan: ShiftPlan, n_positions: int, tb: int) -> int:
@@ -824,25 +844,23 @@ def _shift_forward_kernel(
 ):
     """Spill-mode forward launch: data-register pass, base fidelity, and the
     tile-boundary prefix states written to HBM (``bnd_ref`` rows are
-    [re; im] stacks, one 2*dim block per tile)."""
+    [re; im] stacks, one 2*rows block per tile)."""
     tb = theta_ref.shape[-1]
-    dim = 2**plan.m
-    theta_blk = theta_ref[...]
-    data_blk = data_ref[...]
-    d_re, d_im = _zero_tile(dim, tb)
-    for op in plan.data_ops:
-        d_re, d_im = _apply_one(op, d_re, d_im, plan.m, theta_blk, data_blk)
-    d_ref[...] = jnp.concatenate([d_re, d_im], axis=0)
+    rows = state_rows(plan.m)
+    d_re, d_im = _zero_tile(plan.m, tb)
+    d_re, d_im = _apply_ops(plan.m, plan.data_ops, d_re, d_im, theta_ref, data_ref)
+    d_ref[:rows, :] = d_re
+    d_ref[rows:, :] = d_im
 
     los = {lo: t for t, lo in enumerate(tile_los)}
-    t_re, t_im = _zero_tile(dim, tb)
+    t_re, t_im = _zero_tile(plan.m, tb)
     for k, op in enumerate(plan.train_ops):
         if k in los:
             t = los[k]
-            bnd_ref[2 * t * dim : (2 * t + 1) * dim, :] = t_re
-            bnd_ref[(2 * t + 1) * dim : (2 * t + 2) * dim, :] = t_im
-        t_re, t_im = _apply_one(op, t_re, t_im, plan.m, theta_blk, data_blk)
-    f0_ref[...] = _inner_fidelity((d_re, d_im), (t_re, t_im))[None, :]
+            bnd_ref[2 * t * rows : (2 * t + 1) * rows, :] = t_re
+            bnd_ref[(2 * t + 1) * rows : (2 * t + 2) * rows, :] = t_im
+        t_re, t_im = _apply_one(op, t_re, t_im, plan.m, theta_ref, data_ref)
+    f0_ref[...] = _inner_fidelity((d_re, d_im), (t_re, t_im))
 
 
 def _shift_tile_kernel(
@@ -871,10 +889,8 @@ def _shift_tile_kernel(
     round-trip).  Per-lane op-application order is identical to the serial
     per-tile kernels — results are bit-identical."""
     tb = theta_ref.shape[-1]
-    dim = 2**plan.m
+    dim = state_rows(plan.m)
     i = pl.program_id(0)
-    theta_blk = theta_ref[...]
-    data_blk = data_ref[...]
     bufs = (buf_a, buf_b)
 
     def fetch(slot, pos):
@@ -903,7 +919,7 @@ def _shift_tile_kernel(
                 checkpoints[k] = (re, im)
             if k < last:
                 re, im = _apply_one(
-                    plan.train_ops[k], re, im, plan.m, theta_blk, data_blk
+                    plan.train_ops[k], re, im, plan.m, theta_ref, data_ref
                 )
         # chi walk + per-variant suffix replay, same order as the single
         # sweep; chi at lo seeds the next (shallower) tile directly.
@@ -915,15 +931,16 @@ def _shift_tile_kernel(
                     continue
                 first = plan.theta_positions[j][0]
                 v = _replay_variant(
-                    plan, j, s, checkpoints[first], theta_blk, data_blk
+                    plan, j, s, checkpoints[first], theta_ref, data_ref
                 )
                 rows[g] = _inner_fidelity((c_re, c_im), v)
             if k > lo or pos + 1 < len(tile_plan):
                 c_re, c_im = _apply_one(
-                    op, c_re, c_im, plan.m, theta_blk, data_blk, invert=True
+                    op, c_re, c_im, plan.m, theta_ref, data_ref, invert=True
                 )
         out_rows.extend(rows[g] for g, _, _, _ in rows_t)
-    rows_ref[...] = jnp.stack(out_rows, axis=0)
+    for r, row in enumerate(out_rows):  # one single-sublane store per row
+        rows_ref[r : r + 1, :] = row
 
 
 def _shift_fidelity_spilled(
@@ -946,7 +963,7 @@ def _shift_fidelity_spilled(
     launch observer reports and the trend gate pins."""
     p, lanes = theta_t.shape
     d = data_t.shape[0]
-    dim = 2**plan.m
+    dim = state_rows(plan.m)
     n_tiles = len(tiles)
     grid = (lanes // tb,)
     lane_spec = lambda rows: pl.BlockSpec((rows, tb), lambda i: (0, i))  # noqa: E731
@@ -955,6 +972,8 @@ def _shift_fidelity_spilled(
     variants = _collect_variants(plan, shifts, groups, spec.n_theta)
     fwd = pl.pallas_call(
         functools.partial(_shift_forward_kernel, plan, tuple(lo for lo, _ in tiles)),
+        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
         grid=grid,
         in_specs=in_specs,
         out_specs=[lane_spec(1), lane_spec(2 * dim), lane_spec(2 * n_tiles * dim)],
@@ -963,7 +982,6 @@ def _shift_fidelity_spilled(
             jax.ShapeDtypeStruct((2 * dim, lanes), jnp.float32),
             jax.ShapeDtypeStruct((2 * n_tiles * dim, lanes), jnp.float32),
         ],
-        interpret=interpret,
     )(theta_t, data_t)
     f0, d_state, boundaries = fwd
 
@@ -987,9 +1005,11 @@ def _shift_fidelity_spilled(
 
     rows_out = pl.pallas_call(
         functools.partial(_shift_tile_kernel, plan, tile_plan),
+        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
         grid=grid,
         in_specs=in_specs
-        + [lane_spec(2 * dim), pl.BlockSpec(memory_space=pltpu.ANY)],
+        + [lane_spec(2 * dim), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=lane_spec(len(all_rows)),
         out_shape=jax.ShapeDtypeStruct((len(all_rows), lanes), jnp.float32),
         scratch_shapes=[
@@ -997,7 +1017,6 @@ def _shift_fidelity_spilled(
             pltpu.VMEM((2 * dim, tb), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        interpret=interpret,
     )(theta_t, data_t, d_state, boundaries)
     for idx, (g, _, _, _) in enumerate(all_rows):
         rows_by_group[g] = rows_out[idx]
@@ -1038,8 +1057,7 @@ def vqc_shift_fidelity(
             "circuit does not match the SWAP-test product "
             "structure; use the materialized-bank path"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n_shifts = 4 if four_term else 2
     n_groups = 1 + n_shifts * spec.n_theta
     if groups is None:
@@ -1054,13 +1072,14 @@ def vqc_shift_fidelity(
     b = theta.shape[0]
     p, d = theta.shape[1], data.shape[1]
     tb = kernel_tb(b, tb)
-    pad = (-b) % tb
-    theta_t = jnp.pad(theta.astype(jnp.float32), ((0, pad), (0, 0))).T
-    data_t = jnp.pad(data.astype(jnp.float32), ((0, pad), (0, 0))).T
+    theta_t = _lane_major(theta, tb)
+    data_t = _lane_major(data, tb)
+    lanes = theta_t.shape[1]
 
     variants = _collect_variants(plan, shifts, groups, spec.n_theta)
     positions = sorted(k for k in variants if k >= 0)
     tiles = plan_depth_tiles(plan, positions, tb, vmem_budget)
+    tb = _block_lanes(tb, interpret)
     if tiles is not None:
         out = _shift_fidelity_spilled(
             spec, plan, shifts, groups, tiles, theta_t, data_t, tb, interpret
@@ -1071,14 +1090,15 @@ def vqc_shift_fidelity(
     kern = functools.partial(_shiftbank_kernel, plan, shifts, groups, spec.n_theta)
     out = pl.pallas_call(
         kern,
-        grid=((b + pad) // tb,),
+        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
+        grid=(lanes // tb,),
         in_specs=[
             pl.BlockSpec((p, tb), lambda i: (0, i)),
             pl.BlockSpec((d, tb), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((g, tb), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((g, b + pad), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((g, lanes), jnp.float32),
     )(theta_t, data_t)
     return out[:, :b]
 
