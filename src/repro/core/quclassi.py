@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.api.capabilities import capabilities_of
 from repro.core import circuits, fidelity as fid, segmentation, shift_rule
 from repro.core.sim import CircuitSpec
+from repro.obs import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,7 +174,8 @@ def grad_shift(
 
     theta_grads, losses, fids_per_class = [], [], []
     for c, bank in enumerate(banks):
-        fids = shift_rule.run_bank(run, bank)
+        with span("trainer.bank"):
+            fids = shift_rule.run_bank(run, bank)
         f0, f_plus, f_minus = bank.split_results(fids)[:3]
         # class score per image = mean patch fidelity (matches
         # class_fidelities); chain BCE through the per-image MEAN, then
@@ -193,7 +195,8 @@ def grad_shift(
             p2 = dict(params, **wb)
             f = class_fidelities(cfg, p2, images)
             return one_vs_all_loss(f, labels)
-        gw = jax.grad(dense_loss)({"w": params["w"], "b": params["b"]})
+        with span("trainer.dense_grad"):
+            gw = jax.grad(dense_loss)({"w": params["w"], "b": params["b"]})
         grads.update(gw)
     loss = jnp.stack(losses).mean()
     return loss, grads, jnp.stack(fids_per_class, -1)

@@ -18,6 +18,7 @@ import numpy as np
 from repro.core import quclassi
 from repro.core.quclassi import QuClassiConfig
 from repro.data import pipeline
+from repro.obs import span
 from repro.optim import optimizers
 
 
@@ -111,6 +112,9 @@ def train(
         )
     (xtr, ytr), (xte, yte) = train_set, test_set
     xtr, xte = pipeline.clean(xtr), pipeline.clean(xte)
+    # program spans record into the serving runtime's trace recorder; the
+    # spans grad_shift opens inherit it
+    recorder = gateway.telemetry.trace if gateway is not None else None
     params = quclassi.init_params(cfg, jax.random.PRNGKey(seed))
     opt = optimizers.make(optimizer, lr)
     opt_state = opt.init(params)
@@ -122,24 +126,27 @@ def train(
         for bi, (xb, yb) in enumerate(
             pipeline.batches(xtr, ytr, batch_size, seed=seed * 997 + epoch)
         ):
-            xb, yb = jnp.asarray(xb), jnp.asarray(yb)
-            if grad_mode == "shift":
-                loss, grads, _ = quclassi.grad_shift(
-                    cfg, params, xb, yb, executor=executor, implicit=implicit
-                )
-                n_circ += quclassi.total_bank_circuits(cfg, xb.shape[0])
-            else:
-                loss, grads, _ = quclassi.grad_autodiff(cfg, params, xb, yb)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optimizers.apply_updates(params, updates)
-            losses.append(float(loss))
+            with span("trainer.step", recorder):
+                xb, yb = jnp.asarray(xb), jnp.asarray(yb)
+                if grad_mode == "shift":
+                    loss, grads, _ = quclassi.grad_shift(
+                        cfg, params, xb, yb, executor=executor, implicit=implicit
+                    )
+                    n_circ += quclassi.total_bank_circuits(cfg, xb.shape[0])
+                else:
+                    loss, grads, _ = quclassi.grad_autodiff(cfg, params, xb, yb)
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optimizers.apply_updates(params, updates)
+                losses.append(float(loss))
         wall = time.perf_counter() - t0               # lines 24-25
-        tr_acc = float(
-            quclassi.accuracy(cfg, params, jnp.asarray(xtr), jnp.asarray(ytr))
-        )
-        te_acc = float(
-            quclassi.accuracy(cfg, params, jnp.asarray(xte), jnp.asarray(yte))
-        )
+        with span("trainer.accuracy", recorder):
+            tr_acc = float(
+                quclassi.accuracy(cfg, params, jnp.asarray(xtr), jnp.asarray(ytr))
+            )
+        with span("trainer.accuracy", recorder):
+            te_acc = float(
+                quclassi.accuracy(cfg, params, jnp.asarray(xte), jnp.asarray(yte))
+            )
         rec = EpochRecord(epoch, float(np.mean(losses)), tr_acc, te_acc, wall, n_circ)
         records.append(rec)                           # line 26: accuracy/epoch
         if log:

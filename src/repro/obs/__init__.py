@@ -3,7 +3,8 @@
 One recorder serves both runtimes (virtual-clock simulation and real
 kernel dispatchers) because every clock in the stack is a caller-supplied
 float.  See ``trace.TraceRecorder`` for the hook surface and
-``histogram.LogHistogram`` for the fixed-memory aggregation primitive.
+``histogram.LogHistogram`` for the fixed-memory aggregation primitive, and
+``spans.span`` for the trainer's and the data plane's program spans.
 """
 from repro.obs.config import (
     FEDERATED_STAGES,
@@ -12,6 +13,7 @@ from repro.obs.config import (
     ObservabilityConfig,
 )
 from repro.obs.histogram import LogHistogram
+from repro.obs.spans import span
 from repro.obs.trace import (
     OUTCOMES,
     STAGE_METRICS,
@@ -38,5 +40,6 @@ __all__ = [
     "TraceRecorder",
     "WorkerSpan",
     "WorkerTimeline",
+    "span",
     "validate_trace",
 ]

@@ -52,6 +52,8 @@ STAGE_METRICS = {
     "complete": "execute",
 }
 
+_LIFECYCLE_METRICS = frozenset(STAGE_METRICS.values())
+
 #: terminal transitions closing a circuit trace.
 OUTCOMES = ("complete", "evict", "fail", "reject")
 
@@ -349,7 +351,13 @@ class TraceRecorder:
     when enabled, per-circuit records are sampled deterministically by
     sequence number while histograms and worker timelines stay always-on
     (they are O(1) memory).  Thread-safe: async dispatcher worker slots
-    record concurrently with the pump thread."""
+    record concurrently with the pump thread.
+
+    ``stage_hists`` holds, by name, the lifecycle stage latencies
+    (``STAGE_METRICS`` values, from sampled circuits) and the program
+    spans and compile counter of ``repro.obs.spans`` (``record_span``:
+    per step, neither sampled nor stage-filtered, and never in the trace
+    buffer)."""
 
     def __init__(self, config: Optional[ObservabilityConfig] = None):
         self.config = config or ObservabilityConfig()
@@ -556,6 +564,14 @@ class TraceRecorder:
             self.coalescer_depth.record(members)
             self.coalescer_lanes.record(lanes)
 
+    def record_span(self, name: str, seconds: float) -> None:
+        """One program span's host duration (``repro.obs.spans``) into
+        ``stage_hists[name]``."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._hist(name).record(seconds)
+
     def on_kernel_launch(self, info: dict) -> None:
         """Kernel-wrapper hook (``repro.kernels.ops.set_launch_observer``):
         counts shift-plan launches by execution mode (fused / spill /
@@ -582,8 +598,9 @@ class TraceRecorder:
             ]
 
     def stage_summary(self) -> dict:
-        """Aggregate stage-latency accounting: per-metric histogram stats
-        plus each stage's share of total end-to-end latency."""
+        """Aggregate stage-latency accounting: per-histogram stats (lifecycle
+        stages and program spans) plus each lifecycle stage's share of total
+        end-to-end latency."""
         with self._lock:
             out: dict[str, Any] = {}
             for metric in sorted(self.stage_hists):
@@ -591,7 +608,8 @@ class TraceRecorder:
             e2e_total = self.e2e.total
             if self.e2e.count:
                 out["e2e"] = self.e2e.snapshot()
-                for metric in sorted(self.stage_hists):
+                lifecycle = _LIFECYCLE_METRICS.intersection(self.stage_hists)
+                for metric in sorted(lifecycle):
                     share = (
                         self.stage_hists[metric].total / e2e_total
                         if e2e_total > 0

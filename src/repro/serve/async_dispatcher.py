@@ -37,6 +37,7 @@ from typing import Sequence
 from repro.comanager.faults import FaultToleranceConfig
 from repro.comanager.manager import CoManager
 from repro.comanager.worker import CircuitTask, WorkerConfig
+from repro.obs import span
 from repro.serve.coalescer import CoalescedBatch
 from repro.serve.dispatcher import (
     WORKER_VMEM_BYTES,
@@ -321,7 +322,8 @@ class AsyncDispatcher(Dispatcher):
         err: BaseException | None = None
         fids = None
         try:
-            fids = execute_batch(batch, *self._spill_fns())
+            with span("dispatcher.execute", tr):
+                fids = execute_batch(batch, *self._spill_fns())
         except BaseException as exc:
             err = exc
         dt = self.clock() - t0
@@ -386,9 +388,10 @@ class AsyncDispatcher(Dispatcher):
             try:
                 if self.fault_injector is not None:
                     self.fault_injector.check(wid, t0)
-                fids = execute_batch(
-                    batch, self.kernel, self.shift_kernel, self.multibank_kernel
-                )
+                with span("dispatcher.execute", tr):
+                    fids = execute_batch(
+                        batch, self.kernel, self.shift_kernel, self.multibank_kernel
+                    )
                 if self.fault_injector is not None:
                     # mirror the simulation's slowdown fault in wall time
                     extra = (

@@ -44,6 +44,7 @@ from repro.kernels.vqc_statevector import (
     shift_cost_info,
     shift_execution_info,
 )
+from repro.obs import span
 from repro.serve.coalescer import CoalescedBatch
 from repro.serve.fleet import FaultInjector, FleetHealth
 from repro.serve.gateway import Backpressure, Gateway
@@ -452,7 +453,8 @@ class Dispatcher:
             tr.batch_stage(seqs, "placed", t0, worker="mesh")
             tr.batch_stage(seqs, "dispatched", t0)
             tr.batch_stage(seqs, "kernel_start", t0)
-        fids = execute_batch(batch, *self._spill_fns())
+        with span("dispatcher.execute", tr):
+            fids = execute_batch(batch, *self._spill_fns())
         t1 = self.clock()
         if tr.enabled:
             tr.worker_span(
@@ -507,9 +509,10 @@ class Dispatcher:
             try:
                 if self.fault_injector is not None:
                     self.fault_injector.check(wid, t0)
-                fids = execute_batch(
-                    batch, self.kernel, self.shift_kernel, self.multibank_kernel
-                )
+                with span("dispatcher.execute", tr):
+                    fids = execute_batch(
+                        batch, self.kernel, self.shift_kernel, self.multibank_kernel
+                    )
                 break
             except Exception as exc:
                 err = exc
