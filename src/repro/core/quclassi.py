@@ -18,6 +18,7 @@ Two gradient paths:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable
 
 import jax
@@ -113,7 +114,10 @@ def predict(cfg: QuClassiConfig, params: dict, images: jnp.ndarray) -> jnp.ndarr
     return class_fidelities(cfg, params, images).argmax(-1)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def accuracy(cfg: QuClassiConfig, params: dict, images, labels) -> jnp.ndarray:
+    """Share of ``images`` whose predicted class is ``labels``: one compiled
+    program per (config, shapes)."""
     return (predict(cfg, params, images) == labels).mean()
 
 
@@ -125,6 +129,16 @@ def grad_autodiff(cfg: QuClassiConfig, params: dict, images, labels):
         return one_vs_all_loss(f, labels), f
     (loss, f), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
     return loss, g, f
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _dense_grad(cfg: QuClassiConfig, w, b, theta, images, labels) -> dict:
+    """Exact dense-layer gradient ``{"w", "b"}`` of the one-vs-all loss,
+    holding ``theta`` fixed: one compiled program per (config, shapes)."""
+    def dense_loss(wb):
+        f = class_fidelities(cfg, dict(wb, theta=theta), images)
+        return one_vs_all_loss(f, labels)
+    return jax.grad(dense_loss)({"w": w, "b": b})
 
 
 def build_class_banks(
@@ -162,7 +176,9 @@ def grad_shift(
 
     Dense-layer params, when present, are trained with exact chain-rule
     gradients holding theta fixed (autodiff through the data-encoding path) —
-    see DESIGN.md §2 for why this mirrors the paper's classical update.
+    see DESIGN.md §2 for why this mirrors the paper's classical update.  That
+    gradient is one compiled program (``_dense_grad``) per config and input
+    shapes.
     """
     spec = cfg.spec
     run = executor or shift_rule.default_executor(spec)
@@ -191,13 +207,10 @@ def grad_shift(
 
     grads = {"theta": jnp.stack(theta_grads)}
     if cfg.use_dense:
-        def dense_loss(wb):
-            p2 = dict(params, **wb)
-            f = class_fidelities(cfg, p2, images)
-            return one_vs_all_loss(f, labels)
         with span("trainer.dense_grad"):
-            gw = jax.grad(dense_loss)({"w": params["w"], "b": params["b"]})
-        grads.update(gw)
+            grads.update(_dense_grad(
+                cfg, params["w"], params["b"], params["theta"], images, labels
+            ))
     loss = jnp.stack(losses).mean()
     return loss, grads, jnp.stack(fids_per_class, -1)
 

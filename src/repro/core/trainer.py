@@ -4,6 +4,12 @@ Per epoch (lines 4-26): start timer -> segment data / encode -> build the
 parameter-shift circuit bank -> execute every circuit in the bank through the
 chosen executor (local fused kernel, per-worker batches, or a sharded mesh)
 -> assemble gradients -> update parameters -> stop timer, record accuracy.
+
+The classical half runs as compiled programs: the dense layer's gradient
+(inside ``quclassi.grad_shift``) and each accuracy pass
+(``quclassi.accuracy``) are one jitted call each, keyed on the static
+``QuClassiConfig`` and the input shapes.  ``pipeline.batches`` drops the
+remainder, so every step of a run has the same shapes.
 """
 from __future__ import annotations
 

@@ -95,10 +95,14 @@ def bank_partition(batch: CoalescedBatch):
     """Split a shift-group batch's members into per-bank subtask lists.
 
     Returns ``(banks, group_sets, slots)``: the distinct ``ShiftBank``s in
-    first-appearance order, each bank's requested group tuple, and for every
+    first-appearance order, each bank's whole group tuple, and for every
     member its ``(bank_index, row_index)`` into the fused kernel's per-bank
-    output blocks."""
-    banks, group_sets, slots = [], [], []
+    output blocks.  A launch computes every group of each of its banks,
+    whichever of them the batch carries: which groups a batch carries
+    depends on how the coalescer split concurrent banks between batches,
+    and a kernel program keyed on that split would compile anew for each
+    split it meets."""
+    banks, slots = [], []
     index: dict[int, int] = {}
     for m in batch.members:
         bank, g = m.payload
@@ -106,10 +110,8 @@ def bank_partition(batch: CoalescedBatch):
         if k is None:
             k = index[id(bank)] = len(banks)
             banks.append(bank)
-            group_sets.append([])
-        slots.append((k, len(group_sets[k])))
-        group_sets[k].append(int(g))
-    return banks, [tuple(gs) for gs in group_sets], slots
+        slots.append((k, int(g)))
+    return banks, [tuple(range(b.n_groups)) for b in banks], slots
 
 
 def execute_batch(
@@ -130,9 +132,9 @@ def execute_batch(
     time.  The pad lanes are dead weight the launch already paid for
     (``CoalescedBatch.padded``) and are sliced off before scatter-back."""
     if isinstance(batch.key, ShiftGroupKey):
-        # ONE prefix-reuse kernel launch computes every coalesced
-        # (param, shift) group of every bank in the batch; member i gets
-        # its group's (B,) fidelity row of its bank's block.
+        # ONE prefix-reuse kernel launch computes every (param, shift)
+        # group of every bank in the batch (``bank_partition``); member i
+        # gets its group's (B,) fidelity row of its bank's block.
         spec = batch.key.spec
         banks, group_sets, slots = bank_partition(batch)
         if len(banks) == 1:
@@ -209,7 +211,7 @@ def batch_cost_units(batch: CoalescedBatch) -> float:
     union = tuple(sorted({g for gs in group_sets for g in gs}))
     cost = shift_cost_info(spec, batch.key.four_term, union)
     if not cost["use_implicit"]:
-        # fallback materializes each bank's requested groups separately
+        # fallback materializes each bank's groups separately
         return float(
             len(spec.ops)
             * sum(

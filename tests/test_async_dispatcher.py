@@ -680,3 +680,46 @@ def test_mixed_slo_banks_stay_bit_exact_after_fusion(specs):
         assert rt.telemetry.fused_banks >= 2
     finally:
         rt.close()
+
+
+def test_partial_group_batches_run_the_whole_bank_program(specs):
+    """A batch that carries only some groups of its banks runs the program
+    of whole banks and returns exactly their rows: how the coalescer split
+    concurrent banks between batches compiles nothing new."""
+    from repro.core import shift_rule
+    from repro.serve import ShiftGroupKey, execute_batch
+
+    cfg5, _ = specs
+    theta, data = rows_for(cfg5, 8)
+    banks = [
+        shift_rule.build_shift_bank(theta[k], data[4 * k : 4 * k + 4])
+        for k in range(2)
+    ]
+    key = ShiftGroupKey(cfg5.spec, False)
+    n = banks[0].n_groups
+
+    def run(groups_of):
+        """Execute one batch holding groups ``groups_of[k]`` of bank k."""
+        members = [
+            PendingCircuit(key, "t", 0, 0.0, (banks[k], g), lanes=4)
+            for k, gs in enumerate(groups_of)
+            for g in gs
+        ]
+        batch = CoalescedBatch(key=key, members=members, created=0.0)
+        return [np.asarray(r) for r in execute_batch(
+            batch, kops.vqc_fidelity, kops.vqc_fidelity_shiftgroups
+        )]
+
+    def programs():
+        return (kops._shiftgroups_jit._cache_size(),
+                kops._shiftgroups_multibank_jit._cache_size())
+
+    one, two = run([range(n)]), run([range(n), range(n)])
+    before = programs()
+    part_one = run([(0, 3, n - 1)])
+    part_two = run([(1, 2), (4, n - 1)])
+    assert programs() == before
+    for got, g in zip(part_one, (0, 3, n - 1)):
+        assert np.array_equal(got, one[g])
+    for got, (k, g) in zip(part_two, ((0, 1), (0, 2), (1, 4), (1, n - 1))):
+        assert np.array_equal(got, two[k * n + g])

@@ -15,6 +15,8 @@ from repro.core import quclassi, shift_rule
 from repro.core.quclassi import QuClassiConfig
 from repro.core.trainer import train
 from repro.data import mnist
+from repro.obs import TraceRecorder, span
+from repro.obs.spans import COMPILE_HIST
 
 
 @pytest.fixture(scope="module")
@@ -84,6 +86,52 @@ def test_arbitrary_assignment_same_result(small_data):
     f_dist = ex(bank.theta, bank.data)
     f_local = shift_rule.default_executor(cfg.spec)(bank.theta, bank.data)
     np.testing.assert_allclose(np.asarray(f_dist), np.asarray(f_local), atol=1e-5)
+
+
+def _eager_dense_grad(cfg, p, x, y):
+    """``jax.grad`` of the dense loss, dispatched op by op."""
+    def dense_loss(wb):
+        f = quclassi.class_fidelities(cfg, dict(p, **wb), x)
+        return quclassi.one_vs_all_loss(f, y)
+    return jax.grad(dense_loss)({"w": p["w"], "b": p["b"]})
+
+
+@pytest.mark.parametrize("qc,nl,n_images", [(5, 1, 8), (7, 3, 4)])
+def test_compiled_classical_half_equals_eager(small_data, qc, nl, n_images):
+    """The compiled dense gradient and accuracy pass compute what the eager
+    ``jax.grad`` and ``predict`` compute."""
+    x, y = small_data[0][:n_images], small_data[1][:n_images]
+    cfg = QuClassiConfig(qc=qc, n_layers=nl, use_dense=True)
+    p = quclassi.init_params(cfg, jax.random.PRNGKey(4))
+    _, g, _ = quclassi.grad_shift(cfg, p, x, y)
+    ref = _eager_dense_grad(cfg, p, x, y)
+    for k in ("w", "b"):
+        assert g[k].shape == ref[k].shape
+        assert float(jnp.max(jnp.abs(g[k] - ref[k]))) <= 1e-6, k
+    eager_acc = (quclassi.predict(cfg, p, x) == y).mean()
+    assert float(quclassi.accuracy(cfg, p, x, y)) == float(eager_acc)
+
+
+def test_classical_half_compiles_once_per_shape(small_data):
+    """Calls at shapes seen before compile nothing, whatever the parameter
+    values: the programs are keyed on config and shapes, not on a per-call
+    closure or on ``theta``."""
+    x, y = small_data
+    cfg = QuClassiConfig(qc=5, n_layers=1)
+    run = shift_rule.default_executor(cfg.spec)  # one executor, one program
+    r = TraceRecorder()
+    seen = []
+    for seed in (5, 6):
+        p = quclassi.init_params(cfg, jax.random.PRNGKey(seed))
+        p["b"] = p["b"] + 0.1 * seed
+        with span("trainer.step", r):
+            quclassi.grad_shift(cfg, p, x, y, executor=run)
+            float(quclassi.accuracy(cfg, p, x, y))
+        h = r.stage_hists.get(COMPILE_HIST)
+        seen.append((h.count if h else 0,
+                     quclassi._dense_grad._cache_size(),
+                     quclassi.accuracy._cache_size()))
+    assert seen[1] == seen[0]
 
 
 def test_total_bank_circuits():
