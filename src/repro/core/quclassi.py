@@ -90,18 +90,18 @@ def encode_patches(
 def class_fidelities(
     cfg: QuClassiConfig, params: dict, images: jnp.ndarray
 ) -> jnp.ndarray:
-    """(B, H, W) images -> (B, n_classes) mean patch fidelity per class."""
-    spec = cfg.spec
+    """(B, H, W) images -> (B, n_classes) mean patch fidelity per class.
+
+    F is the overlap of the two registers the SWAP test compares
+    (``fidelity.register_fidelities``): one 2**m-amplitude data-register
+    state per patch and one trainable-register state per class, never a
+    2**qc-amplitude state."""
     patches = segmentation.segment(images, cfg.seg)        # (B, Np, P)
     angles = encode_patches(cfg, params, patches)          # (B, Np, A)
     flat = angles.reshape(-1, angles.shape[-1])            # (B*Np, A)
-
-    def per_class(theta):
-        t = jnp.broadcast_to(theta, (flat.shape[0],) + theta.shape)
-        f = fid.fidelity_batch(spec, t, flat)              # (B*Np,)
-        return f.reshape(angles.shape[0], -1).mean(-1)     # (B,)
-
-    return jax.vmap(per_class)(params["theta"]).T          # (B, C)
+    regs = fid.register_specs(cfg.spec)
+    f = fid.register_fidelities(regs, params["theta"], flat)  # (B*Np, C)
+    return f.reshape(angles.shape[0], -1, f.shape[-1]).mean(1)
 
 
 def one_vs_all_loss(fids: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:

@@ -615,7 +615,9 @@ def _shiftbank_kernel(
 # 2 * (n_tiles + 1) register states of HBM spill traffic.
 
 #: default per-launch checkpoint VMEM budget: ~16 MB/core minus headroom
-#: for the angle blocks, the running states, and double buffering.
+#: for the angle blocks, the running states, and double buffering.  A
+#: ``vmem_budget`` left None reads this when the plan is cut, not when the
+#: module is imported.
 VMEM_BUDGET_BYTES = 14 * 1024 * 1024
 
 #: scoped VMEM each launch may use.  The budget above models the live
@@ -662,7 +664,7 @@ def _merge_spans(plan: ShiftPlan, positions):
 
 
 def plan_depth_tiles(
-    plan: ShiftPlan, positions, tb: int, vmem_budget: int = VMEM_BUDGET_BYTES
+    plan: ShiftPlan, positions, tb: int, vmem_budget: int | None = None
 ):
     """Cut variant anchor positions into depth tiles that fit the budget.
 
@@ -678,6 +680,8 @@ def plan_depth_tiles(
     positions = sorted(positions)
     if not positions:
         return None
+    if vmem_budget is None:
+        vmem_budget = VMEM_BUDGET_BYTES
     cap = max(1, vmem_budget // _state_bytes(plan.m, tb) - _RESERVED_STATES)
     segments = _merge_spans(plan, positions)
     if sum(n for _, n in segments) <= cap:
@@ -771,7 +775,7 @@ def shift_execution_info(
     four_term: bool = False,
     groups: tuple[int, ...] | None = None,
     tb: int = 4 * LANES,
-    vmem_budget: int = VMEM_BUDGET_BYTES,
+    vmem_budget: int | None = None,
 ) -> dict:
     """Static execution-mode report: which path a shift bank takes and what
     it costs.  ``mode`` is "materialize" (no product structure, or replay
@@ -785,6 +789,8 @@ def shift_execution_info(
     n_groups = 1 + n_shifts * spec.n_theta
     if groups is None:
         groups = tuple(range(n_groups))
+    if vmem_budget is None:
+        vmem_budget = VMEM_BUDGET_BYTES
     tb_eff = kernel_tb(n_samples, tb)
     cost = shift_cost_info(spec, four_term, tuple(groups))
     base = {
@@ -970,8 +976,12 @@ def _shift_fidelity_spilled(
     in_specs = [lane_spec(p), lane_spec(d)]
 
     variants = _collect_variants(plan, shifts, groups, spec.n_theta)
+    # both launches are named: a compiled program, and so the device trace,
+    # names their Mosaic calls ``spill_forward`` and ``spill_tiles``; the
+    # fused single-sweep launch keeps its jitted wrapper's name
     fwd = pl.pallas_call(
         functools.partial(_shift_forward_kernel, plan, tuple(lo for lo, _ in tiles)),
+        name="spill_forward",
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
         grid=grid,
@@ -1005,6 +1015,7 @@ def _shift_fidelity_spilled(
 
     rows_out = pl.pallas_call(
         functools.partial(_shift_tile_kernel, plan, tile_plan),
+        name="spill_tiles",
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
         grid=grid,
@@ -1032,7 +1043,7 @@ def vqc_shift_fidelity(
     groups: tuple[int, ...] | None = None,
     tb: int = 4 * LANES,
     interpret: bool | None = None,
-    vmem_budget: int = VMEM_BUDGET_BYTES,
+    vmem_budget: int | None = None,
 ) -> jnp.ndarray:
     """Prefix-reuse shift-bank fidelities. theta: (B,P), data: (B,D).
 
@@ -1136,7 +1147,7 @@ def multibank_stats(
     spec: CircuitSpec,
     bank_sizes,
     four_term: bool = False,
-    vmem_budget: int = VMEM_BUDGET_BYTES,
+    vmem_budget: int | None = None,
 ) -> dict:
     """Analytic launch-count and lane accounting for a fused multi-bank
     shift execution of K same-spec banks vs K per-bank launches.

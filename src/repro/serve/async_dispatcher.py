@@ -448,6 +448,7 @@ class AsyncDispatcher(Dispatcher):
                 tr.worker_span(wid, t0, t0 + dt, args=kernel_span_args(batch))
             self._observe(batch, dt)
             self._record(batch)
+            self._record_spill(batch)
             self.gateway.complete(batch, fids, now)
         elif err is not None and last and not winner_exists:
             bad = self.fleet.unplaceable(now)

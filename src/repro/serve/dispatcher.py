@@ -25,11 +25,13 @@ counterpart lives in ``repro.comanager.simulation`` (``gateway=True``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Callable, Sequence
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.api.capabilities import declare
 from repro.comanager.faults import FaultToleranceConfig
@@ -38,6 +40,7 @@ from repro.comanager.tenancy import TaskIdAllocator
 from repro.comanager.worker import CircuitTask, WorkerConfig
 from repro.core.sim import CircuitSpec
 from repro.kernels import ops as kops
+from repro.kernels import vqc_statevector as kvs
 from repro.kernels.vqc_statevector import (
     LANES,
     kernel_tb,
@@ -165,19 +168,29 @@ def execute_batch(
         )
         return [outs[k][i][: banks[k].n_samples] for k, i in slots]
     spec: CircuitSpec = batch.key
-    theta = jnp.stack([m.payload[0] for m in batch.members])
-    data = jnp.stack([m.payload[1] for m in batch.members])
     n = len(batch.members)
     # bucketing to LANES (not the coalescer's possibly-smaller test-time lane
     # config) is free: the Pallas kernel's internal tile is >= LANES lanes
     # for ANY batch size, so the pad rows add zero kernel work while keeping
     # the number of distinct compiled shapes minimal.
-    pad = (-n) % LANES
-    if pad:
-        theta = jnp.pad(theta, ((0, pad), (0, 0)))
-        data = jnp.pad(data, ((0, pad), (0, 0)))
+    lanes = n + (-n) % LANES
+    theta = _stack_rows([m.payload[0] for m in batch.members], lanes)
+    data = _stack_rows([m.payload[1] for m in batch.members], lanes)
     fids = kernel(spec, theta, data)
     return [fids[i] for i in range(n)]
+
+
+def _stack_rows(rows: list, lanes: int):
+    """The members' rows stacked and zero-padded to ``lanes``.  Host rows
+    are stacked on the host and sent in one transfer; device rows are
+    padded as a list, so the stacking program is one per lane bucket and
+    not one per member count (a deadline flush's count is arbitrary, and a
+    new program compiled on the dispatch path stalls every queued batch)."""
+    if all(isinstance(r, np.ndarray) for r in rows):
+        out = np.zeros((lanes, *rows[0].shape), np.result_type(*rows))
+        out[: len(rows)] = rows
+        return jnp.asarray(out)
+    return jnp.stack(rows + [jnp.zeros_like(rows[0])] * (lanes - len(rows)))
 
 
 # ------------------------------------------------------- analytic cost model
@@ -228,6 +241,28 @@ def batch_cost_units(batch: CoalescedBatch) -> float:
 WORKER_VMEM_BYTES = 16 * 1024 * 1024
 
 
+@functools.lru_cache(maxsize=256)
+def _launch_plan(spec, lanes: int, four_term: bool, groups: tuple, budget: int) -> dict:
+    return shift_execution_info(
+        spec, lanes, four_term=four_term, groups=groups, vmem_budget=budget
+    )
+
+
+def shift_launch_info(batch: CoalescedBatch) -> tuple[int, int, dict]:
+    """``(banks, lanes, shift_execution_info)`` of the one kernel launch a
+    shift-group batch makes: its banks' LANES-padded lane segments side by
+    side, the union of their group sets.  The plan is cached per shape and
+    VMEM budget (the dispatcher asks on every launch); treat it as
+    read-only."""
+    banks, group_sets, _ = bank_partition(batch)
+    lanes = sum(math.ceil(b.n_samples / LANES) * LANES for b in banks)
+    union = tuple(sorted({g for gs in group_sets for g in gs}))
+    info = _launch_plan(
+        batch.key.spec, lanes, batch.key.four_term, union, kvs.VMEM_BUDGET_BYTES
+    )
+    return len(banks), lanes, info
+
+
 def kernel_span_args(batch: CoalescedBatch) -> dict:
     """Trace-span payload for one batch's kernel launch: the shift-plan
     execution shape (``shift_execution_info`` — fused/spill/materialize,
@@ -235,19 +270,14 @@ def kernel_span_args(batch: CoalescedBatch) -> dict:
     row-tile footprint otherwise.  Only computed when tracing is enabled."""
     spec = batch_spec(batch)
     if isinstance(batch.key, ShiftGroupKey):
-        banks, group_sets, _ = bank_partition(batch)
-        lanes = sum(math.ceil(b.n_samples / LANES) * LANES for b in banks)
-        union = tuple(sorted({g for gs in group_sets for g in gs}))
-        info = shift_execution_info(
-            spec, lanes, four_term=batch.key.four_term, groups=union
-        )
+        n_banks, lanes, info = shift_launch_info(batch)
         args = {
             "kind": "shift",
             "mode": info["mode"],
             "launches": info["launches"],
             "n_tiles": info["n_tiles"],
             "vmem_bytes": info["vmem_bytes"],
-            "banks": len(banks),
+            "banks": n_banks,
             "lanes": lanes,
             "members": batch.n,
         }
@@ -282,13 +312,7 @@ def batch_vmem_bytes(batch: CoalescedBatch) -> int:
     per-device tile back under budget)."""
     spec = batch_spec(batch)
     if isinstance(batch.key, ShiftGroupKey):
-        banks, group_sets, _ = bank_partition(batch)
-        lanes = sum(math.ceil(b.n_samples / LANES) * LANES for b in banks)
-        union = tuple(sorted({g for gs in group_sets for g in gs}))
-        info = shift_execution_info(
-            spec, lanes, four_term=batch.key.four_term, groups=union
-        )
-        return info["vmem_bytes"]
+        return shift_launch_info(batch)[2]["vmem_bytes"]
     tb = kernel_tb(batch.padded(LANES))
     return 2 * 4 * (2**spec.n_qubits) * tb
 
@@ -445,6 +469,20 @@ class Dispatcher:
             banks, _, _ = bank_partition(batch)
             self.gateway.telemetry.on_fused_launch(len(banks))
 
+    def _record_spill(self, batch: CoalescedBatch) -> None:
+        """A shift-group batch a worker ran through the depth-tiled spill
+        kernel counts its banks, depth tiles and the boundary states it
+        moved through HBM (``Telemetry.on_spill_launch``).  The whole-mesh
+        path shards lanes and tiles on its own and does not call this."""
+        if not isinstance(batch.key, ShiftGroupKey):
+            return
+        n_banks, lanes, info = shift_launch_info(batch)
+        if info["mode"] == "spill":
+            blocks = math.ceil(lanes / kernel_tb(lanes))
+            self.gateway.telemetry.on_spill_launch(
+                n_banks, info["n_tiles"], info["spilled_bytes"] * blocks
+            )
+
     def run_spilled(self, batch: CoalescedBatch) -> str:
         """Execute one oversized batch on the whole device mesh (no single
         worker is charged — the spill path is its own resource)."""
@@ -559,6 +597,7 @@ class Dispatcher:
             tr.worker_span(wid, t0, t1, args=kernel_span_args(batch))
         self._observe(batch, t1 - t0)
         self._record(batch)
+        self._record_spill(batch)
         self._charge(wid, -est)
         self.manager.complete(wid, task, self.clock())
         self.fleet.on_success(wid)

@@ -182,6 +182,13 @@ class Telemetry:
         # failing fast.
         self.mesh_spills = 0
         self.spilled_lanes = 0
+        # depth-tiled spill launches on a worker (register too wide for the
+        # kernel's VMEM checkpoint budget): banks they covered, depth tiles
+        # summed over launches, and bytes of boundary states written to HBM
+        # and read back.
+        self.spill_banks = 0
+        self.spill_tiles = 0
+        self.spill_boundary_bytes = 0
         # failure recovery: batches sent back through the coalescer after a
         # worker failure or drain (each re-coalesces and re-places
         # bit-identically), plus per-worker fleet event counters.
@@ -237,6 +244,14 @@ class Telemetry:
         """One mega-batch routed through the whole-mesh spill executor."""
         self.mesh_spills += 1
         self.spilled_lanes += lanes
+
+    def on_spill_launch(self, n_banks: int, n_tiles: int, boundary_bytes: int) -> None:
+        """One shift-group launch a worker ran through the depth-tiled
+        spill kernel: ``n_banks`` banks, ``n_tiles`` depth tiles,
+        ``boundary_bytes`` of HBM boundary-state traffic."""
+        self.spill_banks += n_banks
+        self.spill_tiles += n_tiles
+        self.spill_boundary_bytes += boundary_bytes
 
     def on_evict(self, client_id: str) -> None:
         """One circuit preemptively evicted past its SLO budget: counts as

@@ -723,3 +723,46 @@ def test_partial_group_batches_run_the_whole_bank_program(specs):
         assert np.array_equal(got, one[g])
     for got, (k, g) in zip(part_two, ((0, 1), (0, 2), (1, 4), (1, n - 1))):
         assert np.array_equal(got, two[k * n + g])
+
+
+@pytest.mark.parametrize("host", [True, False], ids=["numpy_rows", "device_rows"])
+def test_row_batches_of_one_bucket_share_their_programs(specs, host):
+    """Row batches of any member count within one lane bucket run the
+    programs of the first (deadline flushes emit arbitrary counts; a new
+    program per count would compile on the dispatch path), and each member
+    gets the fidelity of its own row."""
+    import jax
+
+    from repro.serve import execute_batch
+
+    cfg5, _ = specs
+    theta, data = rows_for(cfg5, 9, seed=3)
+    if host:
+        theta, data = np.asarray(theta), np.asarray(data)
+
+    def run(n):
+        members = [
+            PendingCircuit(cfg5.spec, "t", i, 0.0, (theta[i], data[i])) for i in range(n)
+        ]
+        batch = CoalescedBatch(key=cfg5.spec, members=members, created=0.0)
+        return [np.asarray(f) for f in execute_batch(
+            batch, kops.vqc_fidelity, kops.vqc_fidelity_shiftgroups
+        )]
+
+    compiles = []
+
+    def on_compile(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kw.get("fun_name"))
+
+    jax.clear_caches()
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        run(3)
+        before = len(compiles)
+        got = run(7)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert compiles[before:] == []
+    want = np.asarray(kops.vqc_fidelity(cfg5.spec, jnp.asarray(theta[:7]), jnp.asarray(data[:7])))
+    np.testing.assert_allclose(np.stack(got), want, rtol=0, atol=1e-6)
