@@ -30,6 +30,7 @@ import math
 import time
 from typing import Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -137,7 +138,8 @@ def execute_batch(
     if isinstance(batch.key, ShiftGroupKey):
         # ONE prefix-reuse kernel launch computes every (param, shift)
         # group of every bank in the batch (``bank_partition``); member i
-        # gets its group's (B,) fidelity row of its bank's block.
+        # gets its group's (B,) fidelity row of its bank's block, cut out
+        # by one ``_split_rows`` program per block.
         spec = batch.key.spec
         banks, group_sets, slots = bank_partition(batch)
         if len(banks) == 1:
@@ -148,7 +150,8 @@ def execute_batch(
                 banks[0].four_term,
                 group_sets[0],
             )
-            return [rows[i] for _, i in slots]
+            parts = _split_rows(rows, rows.shape[1])
+            return [parts[i] for _, i in slots]
         # per-bank lane bucketing BEFORE the jit boundary: deadline flushes
         # mix arbitrary sample counts, and without rounding each bank to a
         # LANES multiple every new (B_0, B_1, ...) combination would compile
@@ -166,7 +169,8 @@ def execute_batch(
             batch.key.four_term,
             tuple(group_sets),
         )
-        return [outs[k][i][: banks[k].n_samples] for k, i in slots]
+        parts = [_split_rows(out, b.n_samples) for out, b in zip(outs, banks)]
+        return [parts[k][i] for k, i in slots]
     spec: CircuitSpec = batch.key
     n = len(batch.members)
     # bucketing to LANES (not the coalescer's possibly-smaller test-time lane
@@ -191,6 +195,16 @@ def _stack_rows(rows: list, lanes: int):
         out[: len(rows)] = rows
         return jnp.asarray(out)
     return jnp.stack(rows + [jnp.zeros_like(rows[0])] * (lanes - len(rows)))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _split_rows(block, n: int) -> tuple:
+    """Every row of a bank's ``(G, B)`` output block, cut to its first ``n``
+    lanes, as one program with G outputs.  An eager index per member would
+    cost a slice and a squeeze, each its own dispatch and allocation; this
+    program is keyed only on the block's shape and ``n``, never on which of
+    the bank's groups a batch carries (``bank_partition``)."""
+    return tuple(block[g, :n] for g in range(block.shape[0]))
 
 
 # ------------------------------------------------------- analytic cost model
