@@ -1,9 +1,11 @@
-"""Async serving runtime: non-blocking dispatch with per-worker slots.
+"""Async serving runtime: non-blocking driver of the dispatch core.
 
-The synchronous ``Dispatcher`` executes every coalesced mega-batch inline on
-the submitting thread, so one slow kernel launch head-of-line-blocks every
-tenant — exactly the uncontrolled behavior the paper's co-Manager exists to
-avoid.  ``AsyncDispatcher`` decouples the stages:
+The synchronous driver (``Dispatcher.run_batch``) executes every coalesced
+mega-batch inline on the submitting thread, so one slow kernel launch
+head-of-line-blocks every tenant — exactly the uncontrolled behavior the
+paper's co-Manager exists to avoid.  ``AsyncDispatcher`` drives the same
+core steps (``_place``, ``_execute``, ``_execute_spilled``) from decoupled
+stages:
 
   * a PUMP THREAD moves admitted circuits through the weighted-fair
     scheduler and the coalescer, places emitted batches via Algorithm 2,
@@ -21,9 +23,9 @@ worker's CRU for the time it is outstanding (see ``repro.serve.dispatcher``),
 so Algorithm 2 keeps steering work toward the least-loaded worker even
 though completions now arrive asynchronously.
 
-Locking: the gateway has its own re-entrant lock; this class guards its
-scheduler state (ready queue, slot counts, co-Manager views) with one
-condition variable.  The two are never held nested in the
+Locking: the gateway has its own re-entrant lock; the core's condition
+variable ``_cv`` guards the scheduler state (ready queue, slot counts,
+co-Manager views).  The two are never held nested in the
 gateway-then-condition order, so there is no lock-ordering cycle.
 """
 from __future__ import annotations
@@ -34,23 +36,9 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Sequence
 
-from repro.comanager.faults import FaultToleranceConfig
-from repro.comanager.manager import CoManager
-from repro.comanager.worker import CircuitTask, WorkerConfig
-from repro.obs import span
+from repro.comanager.worker import WorkerConfig
 from repro.serve.coalescer import CoalescedBatch
-from repro.serve.dispatcher import (
-    WORKER_VMEM_BYTES,
-    Dispatcher,
-    KernelFn,
-    MultiBankKernelFn,
-    ShiftKernelFn,
-    batch_cost_units,
-    batch_family,
-    execute_batch,
-    kernel_span_args,
-)
-from repro.serve.fleet import FaultInjector
+from repro.serve.dispatcher import Dispatcher
 from repro.serve.gateway import Gateway
 
 
@@ -67,33 +55,12 @@ class AsyncDispatcher(Dispatcher):
         gateway: Gateway,
         workers: Sequence[WorkerConfig],
         *,
-        manager: CoManager | None = None,
-        kernel: KernelFn | None = None,
-        shift_kernel: ShiftKernelFn | None = None,
-        multibank_kernel: MultiBankKernelFn | None = None,
-        mesh_spill: bool = True,
-        spill_executor=None,
-        worker_vmem_bytes: int = WORKER_VMEM_BYTES,
-        evict_over_slo: bool = False,
-        clock=time.perf_counter,
         slots_per_worker: int = 1,
-        fault_tolerance: FaultToleranceConfig | None = None,
-        fault_injector: FaultInjector | None = None,
+        evict_over_slo: bool = False,
+        **kw,
     ):
-        super().__init__(
-            gateway,
-            workers,
-            manager=manager,
-            kernel=kernel,
-            shift_kernel=shift_kernel,
-            multibank_kernel=multibank_kernel,
-            mesh_spill=mesh_spill,
-            spill_executor=spill_executor,
-            worker_vmem_bytes=worker_vmem_bytes,
-            clock=clock,
-            fault_tolerance=fault_tolerance,
-            fault_injector=fault_injector,
-        )
+        """``kw``: the ``Dispatcher`` options."""
+        super().__init__(gateway, workers, **kw)
         if slots_per_worker < 1:
             raise ValueError(f"slots_per_worker must be >= 1, got {slots_per_worker}")
         self.slots_per_worker = slots_per_worker
@@ -103,7 +70,6 @@ class AsyncDispatcher(Dispatcher):
         #: can still make its deadline.  Off by default — eviction turns
         #: late results into errors, which only SLO-strict serving wants.
         self.evict_over_slo = evict_over_slo
-        self._cv = threading.Condition()
         self._slot_free = {w.worker_id: slots_per_worker for w in workers}
         self._spill_slot_free = True  # one whole-mesh batch at a time
         self._ready: list[CoalescedBatch] = []
@@ -114,9 +80,6 @@ class AsyncDispatcher(Dispatcher):
         self._errors: deque[BaseException] = deque(maxlen=self.ERRORS_CAPACITY)
         self._errors_dropped = 0
         self._pump_errors: list[BaseException] = []
-        # in-flight runner registry for hedging and first-result-wins:
-        # id(batch) -> {batch, outstanding, winner, wid, t0, est, hedged}
-        self._runners: dict[int, dict] = {}
         # +1 thread: the whole-mesh spill slot runs alongside full worker pools
         self._pool = ThreadPoolExecutor(
             max_workers=len(workers) * slots_per_worker + 1,
@@ -235,7 +198,7 @@ class AsyncDispatcher(Dispatcher):
         fully-over-SLO batches are preemptively evicted when enabled."""
         while True:
             now = self.clock()
-            launch = spill = evict = None
+            job = evict = None
             with self._cv:
                 exclude = {
                     w for w, free in self._slot_free.items() if free <= 0
@@ -249,7 +212,9 @@ class AsyncDispatcher(Dispatcher):
                             continue  # mesh busy; later batches may fit workers
                         self._spill_slot_free = False
                         self._in_flight += 1
-                        spill = self._ready.pop(i)
+                        self._ready.pop(i)
+                        self._stage(batch, now, "placed", worker="mesh")
+                        job = (self._execute_spilled, batch)
                         break
                     width = self._width(batch)
                     if not self.mesh_spill and width > self._max_width:
@@ -264,21 +229,13 @@ class AsyncDispatcher(Dispatcher):
                         self._push_error_locked(err)
                         self.gateway.fail(batch, err, now)
                         break
-                    est = self._estimate_s(batch)
-                    task = CircuitTask(
-                        task_id=next(self.task_ids),
-                        client_id="gateway",
-                        demand=self._width(batch),
-                        service_time=est,
-                    )
-                    wid = self.manager.assign(task, now, exclude=exclude)
-                    if wid is None:
+                    placed = self._place(batch, now, exclude)
+                    if placed is None:
                         continue
+                    _, wid, est = placed
                     self._ready.pop(i)
                     self._slot_free[wid] -= 1
                     self._in_flight += 1
-                    self._charge(wid, est)
-                    self.fleet.on_dispatch(wid)
                     self._runners[id(batch)] = {
                         "batch": batch,
                         "outstanding": 1,
@@ -288,207 +245,28 @@ class AsyncDispatcher(Dispatcher):
                         "est": est,
                         "hedged": False,
                     }
-                    launch = (batch, task, wid, est)
+                    job = (self._execute, batch, *placed)
                     break
                 else:
                     return  # nothing placeable right now
-            tr = self.gateway.telemetry.trace
             if evict is not None:
                 self.gateway.evict(evict, now)
-            elif spill is not None:
-                if tr.enabled:
-                    tr.batch_stage(
-                        (m.seq for m in spill.members), "placed", now,
-                        worker="mesh",
-                    )
-                self._pool.submit(self._run_spill, spill)
-            elif launch is not None:
-                if tr.enabled:
-                    tr.batch_stage(
-                        (m.seq for m in launch[0].members), "placed", now,
-                        worker=launch[2],
-                    )
-                self._pool.submit(self._run, *launch)
+            elif job is not None:
+                self._pool.submit(*job)
 
-    def _run_spill(self, batch: CoalescedBatch) -> None:
-        """Spill-slot thread: execute one oversized batch on the whole
-        device mesh, resolve its futures, release the spill slot."""
-        tr = self.gateway.telemetry.trace
-        t0 = self.clock()
-        if tr.enabled:
-            seqs = [m.seq for m in batch.members]
-            tr.batch_stage(seqs, "dispatched", t0)
-            tr.batch_stage(seqs, "kernel_start", t0)
-        err: BaseException | None = None
-        fids = None
-        try:
-            with span("dispatcher.execute", tr):
-                fids = execute_batch(batch, *self._spill_fns())
-        except BaseException as exc:
-            err = exc
-        dt = self.clock() - t0
-        now = self.clock()
-        if err is None:
-            if tr.enabled:
-                tr.worker_span(
-                    "mesh", t0, t0 + dt, kind="spill",
-                    args=kernel_span_args(batch),
-                )
-            self.gateway.telemetry.service.update(
-                ("spill", batch_family(batch)), batch_cost_units(batch), dt
-            )
-            self.gateway.telemetry.on_spill(batch.lane_count)
-            self._record(batch)
-            self.gateway.complete(batch, fids, now)
-        else:
-            self.gateway.fail(batch, err, now)
-        with self._cv:
+    def _release(self, wid: str | None, err: BaseException | None) -> None:
+        """Free the settled batch's slot (the spill slot when ``wid`` is
+        None), ring its error, and wake the pump: ready batches may now
+        place."""
+        if wid is None:
             self._spill_slot_free = True
-            self._in_flight -= 1
-            self.batch_log.append(
-                ("mesh", batch.n, tuple(sorted(batch.clients())))
-            )
-            if err is not None:
-                self._push_error_locked(err)
-            self._kicked = True
-            self._cv.notify_all()
-
-    def _run(
-        self,
-        batch: CoalescedBatch,
-        task: CircuitTask | None,
-        wid: str,
-        est: float,
-        hedge: bool = False,
-    ) -> None:
-        """Worker-slot thread: execute one batch, resolve its futures (out
-        of submission order relative to other batches), release the slot.
-
-        Failure tolerance: a failed attempt retries in place (bounded by
-        ``FaultToleranceConfig.retry_limit`` with exponential backoff), then
-        the batch migrates to a surviving worker through the gateway's
-        re-coalescing requeue.  With hedging, two runners may race on one
-        batch: the first success claims it (resolving the futures exactly
-        once) and the loser's result is discarded — kernel launches cannot
-        be interrupted, so safe cancellation means the loser lands without
-        side effects."""
-        tel = self.gateway.telemetry
-        tr = tel.trace
-        seqs = [m.seq for m in batch.members]
-        t0 = self.clock()
-        if tr.enabled and not hedge:
-            tr.batch_stage(seqs, "dispatched", t0)
-            tr.batch_stage(seqs, "kernel_start", t0)
-        err: BaseException | None = None
-        fids = None
-        attempts = 0
-        while True:
-            t0 = self.clock()
-            err = None
-            try:
-                if self.fault_injector is not None:
-                    self.fault_injector.check(wid, t0)
-                with span("dispatcher.execute", tr):
-                    fids = execute_batch(
-                        batch, self.kernel, self.shift_kernel, self.multibank_kernel
-                    )
-                if self.fault_injector is not None:
-                    # mirror the simulation's slowdown fault in wall time
-                    extra = (
-                        self.fault_injector.slowdown_factor(wid, t0) - 1.0
-                    ) * (self.clock() - t0)
-                    if extra > 0:
-                        time.sleep(extra)
-            except BaseException as exc:
-                err = exc
-            if err is None:
-                break
-            now = self.clock()
-            tripped = self.fleet.on_failure(wid, now)
-            tel.on_worker_failure(wid)
-            if tripped:
-                tel.on_worker_offline(wid)
-                if tr.enabled:
-                    tr.batch_stage(seqs, "worker_offline", now, worker=wid)
-            attempts += 1
-            if (
-                not hedge
-                and attempts <= self.ft.retry_limit
-                and self.fleet.retryable(wid, now)
-            ):
-                self.fleet.record_retry(wid)
-                tel.on_worker_retry(wid)
-                if tr.enabled:
-                    tr.batch_stage(seqs, "retried", now, worker=wid)
-                if self.ft.retry_backoff_s:
-                    time.sleep(self.ft.retry_backoff_s * 2 ** (attempts - 1))
-                continue
-            break
-        dt = self.clock() - t0
-        now = self.clock()
-        # settle against the (possibly hedged) runner set: the first
-        # successful runner claims the batch, the LAST failed runner with
-        # no winner owns migration/terminal failure.
-        with self._cv:
-            entry = self._runners.get(id(batch))
-            if entry is not None:
-                entry["outstanding"] -= 1
-                last = entry["outstanding"] <= 0
-                claimed = err is None and entry["winner"] is None
-                if claimed:
-                    entry["winner"] = wid
-                winner_exists = entry["winner"] is not None
-                if last:
-                    self._runners.pop(id(batch), None)
-            else:  # defensive: every launch registers an entry
-                last, claimed, winner_exists = True, err is None, err is None
-        migrated = False
-        if claimed:
-            if tr.enabled:
-                tr.worker_span(wid, t0, t0 + dt, args=kernel_span_args(batch))
-            self._observe(batch, dt)
-            self._record(batch)
-            self._record_spill(batch)
-            self.gateway.complete(batch, fids, now)
-        elif err is not None and last and not winner_exists:
-            bad = self.fleet.unplaceable(now)
-            with self._cv:
-                survivors = [
-                    w
-                    for w, v in self.manager.workers.items()
-                    if w != wid
-                    and w not in bad
-                    and v.max_qubits >= self._width(batch)
-                ]
-            if survivors:
-                migrated = True
-                self.fleet.record_migration(wid)
-                tel.on_worker_migration(wid)
-                if tr.enabled:
-                    tr.batch_stage(seqs, "migrated", now, worker=wid)
-                self.gateway.requeue(batch, now)
-            else:
-                self.gateway.fail(batch, err, now)
-        if err is None:
-            self.fleet.on_success(wid)
-        # futures are resolved BEFORE the slot is released, so drain()'s
-        # "no in-flight batches" implies "every future resolved".
-        with self._cv:
-            if task is not None:
-                self.manager.complete(wid, task, now)
-            self._charge(wid, -est)
-            if wid in self._slot_free:  # the worker may have been drained
-                self._slot_free[wid] += 1
-            self._in_flight -= 1
-            self.fleet.on_release(wid)
-            if claimed or (err is not None and last and not winner_exists):
-                self.batch_log.append(
-                    (wid, batch.n, tuple(sorted(batch.clients())))
-                )
-            if err is not None and last and not winner_exists and not migrated:
-                self._push_error_locked(err)
-            self._kicked = True  # freed capacity: ready batches may now place
-            self._cv.notify_all()
+        elif wid in self._slot_free:  # the worker may have been drained
+            self._slot_free[wid] += 1
+        self._in_flight -= 1
+        if err is not None:
+            self._push_error_locked(err)
+        self._kicked = True
+        self._cv.notify_all()
 
     def _maybe_hedge(self) -> None:
         """Hedged duplicate dispatch: an in-flight batch whose slot has
@@ -527,16 +305,11 @@ class AsyncDispatcher(Dispatcher):
                 self._charge(wid2, entry["est"])
                 self.fleet.on_dispatch(wid2)
                 launches.append((batch, entry["wid"], wid2, entry["est"]))
-        tel = self.gateway.telemetry
-        tr = tel.trace
         for batch, straggler, wid2, est in launches:
             self.fleet.record_hedge(straggler)
-            tel.on_worker_hedge(straggler)
-            if tr.enabled:
-                tr.batch_stage(
-                    (m.seq for m in batch.members), "hedged", now, worker=wid2
-                )
-            self._pool.submit(self._run, batch, None, wid2, est, True)
+            self.gateway.telemetry.on_worker_hedge(straggler)
+            self._stage(batch, now, "hedged", worker=wid2)
+            self._pool.submit(self._execute, batch, None, wid2, est, True)
 
     # ------------------------------------------------------------- control
     def pump(self) -> int:

@@ -15,10 +15,11 @@ AND is charged to the assigned worker's CRU while the batch is outstanding,
 so Algorithm 2's lowest-CRU-first choice routes new batches toward the
 worker with the least predicted backlog.
 
-This module is the *synchronous real-execution* runtime: execution happens
-inline on the chosen worker's mesh slice (here: the local device) and
-capacity is released immediately after.  The non-blocking counterpart with
-a pump loop and per-worker execution slots is
+This module holds the *dispatch core* — placement (``Dispatcher._place``),
+execute-and-settle on a worker (``_execute``) and the whole-mesh path
+(``_execute_spilled``) — and its synchronous driver, which runs each batch
+inline on the submitting thread.  The non-blocking driver of the same core,
+with a pump loop and per-worker execution slots, is
 ``repro.serve.async_dispatcher.AsyncDispatcher``; the virtual-clock
 counterpart lives in ``repro.comanager.simulation`` (``gateway=True``).
 """
@@ -27,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import threading
 import time
 from typing import Callable, Sequence
 
@@ -332,6 +334,15 @@ def batch_vmem_bytes(batch: CoalescedBatch) -> int:
 
 
 class Dispatcher:
+    """The dispatch core and its synchronous driver.
+
+    Both drivers reach a batch's execution through the same three steps:
+    ``_place`` (Algorithm 2 placement and the CRU charge), ``_execute``
+    (retries, breaker, migration, terminal failure, settle) and
+    ``_execute_spilled`` (the whole-mesh path).  This class calls them
+    inline; ``AsyncDispatcher`` calls them from its pump thread and worker
+    pool, and keeps its slot bookkeeping in the ``_release`` hook."""
+
     def __init__(
         self,
         gateway: Gateway,
@@ -371,6 +382,12 @@ class Dispatcher:
         self.fault_injector = fault_injector
         if fault_injector is not None:
             fault_injector.start(self.clock())
+        #: guards the co-Manager views, the runner registry and
+        #: ``batch_log``; only the async driver's threads contend for it
+        self._cv = threading.Condition()
+        # in-flight runner registry for hedging and first-result-wins:
+        # id(batch) -> {batch, outstanding, winner, wid, t0, est, hedged}
+        self._runners: dict[int, dict] = {}
         self._max_width = max((w.max_qubits for w in workers), default=0)
         for w in workers:
             self._register(w)
@@ -497,41 +514,20 @@ class Dispatcher:
                 n_banks, info["n_tiles"], info["spilled_bytes"] * blocks
             )
 
-    def run_spilled(self, batch: CoalescedBatch) -> str:
-        """Execute one oversized batch on the whole device mesh (no single
-        worker is charged — the spill path is its own resource)."""
+    def _stage(self, batch: CoalescedBatch, now: float, *stages, worker=None):
+        """Record trace ``stages`` at ``now`` for every member of ``batch``."""
         tr = self.gateway.telemetry.trace
-        t0 = self.clock()
         if tr.enabled:
             seqs = [m.seq for m in batch.members]
-            tr.batch_stage(seqs, "placed", t0, worker="mesh")
-            tr.batch_stage(seqs, "dispatched", t0)
-            tr.batch_stage(seqs, "kernel_start", t0)
-        with span("dispatcher.execute", tr):
-            fids = execute_batch(batch, *self._spill_fns())
-        t1 = self.clock()
-        if tr.enabled:
-            tr.worker_span(
-                "mesh", t0, t1, kind="spill", args=kernel_span_args(batch)
-            )
-        self.gateway.telemetry.service.update(
-            ("spill", batch_family(batch)),
-            batch_cost_units(batch),
-            t1 - t0,
-        )
-        self.gateway.telemetry.on_spill(batch.lane_count)
-        self._record(batch)
-        self.gateway.complete(batch, fids, self.clock())
-        self.batch_log.append(("mesh", batch.n, tuple(sorted(batch.clients()))))
-        return "mesh"
+            for stage in stages:
+                tr.batch_stage(seqs, stage, now, worker)
 
-    def run_batch(self, batch: CoalescedBatch) -> str:
-        """Place one batch via Algorithm 2 and execute it on the spot,
-        retrying in place on failure and then migrating the batch to a
-        surviving worker through the gateway's re-coalescing requeue."""
-        now = self.clock()
-        if self.mesh_spill and self._oversized(batch):
-            return self.run_spilled(batch)
+    # ----------------------------------------------------- the dispatch core
+    def _place(self, batch: CoalescedBatch, now: float, exclude):
+        """Algorithm 2 placement on a worker outside ``exclude``: returns
+        ``(task, wid, est)`` with the EWMA estimate ``est`` charged to the
+        worker's CRU, or None when no worker fits.  The async driver calls
+        this under ``_cv``."""
         est = self._estimate_s(batch)
         task = CircuitTask(
             task_id=next(self.task_ids),
@@ -539,24 +535,39 @@ class Dispatcher:
             demand=self._width(batch),
             service_time=est,
         )
-        wid = self.manager.assign(task, now, exclude=self.fleet.unplaceable(now))
+        wid = self.manager.assign(task, now, exclude=exclude)
         if wid is None:
-            if self.mesh_spill:
-                return self.run_spilled(batch)
-            caps = [v.max_qubits for v in self.manager.workers.values()]
-            raise RuntimeError(
-                f"no worker fits a {task.demand}-qubit batch (capacities: {caps})"
-            )
+            return None
         self._charge(wid, est)
         self.fleet.on_dispatch(wid)
+        self._stage(batch, now, "placed", worker=wid)
+        return task, wid, est
+
+    def _execute(
+        self,
+        batch: CoalescedBatch,
+        task: CircuitTask | None,
+        wid: str,
+        est: float,
+        hedge: bool = False,
+    ) -> BaseException | None:
+        """Execute one placed batch on ``wid``, resolve or hand on its
+        futures, release the worker.  Returns the error that failed the
+        futures; None when the batch completed or migrated.
+
+        Failure tolerance: a failed attempt retries in place (bounded by
+        ``FaultToleranceConfig.retry_limit`` with exponential backoff), then
+        the batch migrates to a surviving worker through the gateway's
+        re-coalescing requeue.  With hedging, two runners may race on one
+        batch: the first success claims it (resolving the futures exactly
+        once) and the loser's result is discarded — kernel launches cannot
+        be interrupted, so safe cancellation means the loser lands without
+        side effects."""
         tel = self.gateway.telemetry
         tr = tel.trace
-        seqs = [m.seq for m in batch.members]
-        t0 = self.clock()
-        if tr.enabled:
-            tr.batch_stage(seqs, "placed", t0, worker=wid)
-            tr.batch_stage(seqs, "dispatched", t0)
-            tr.batch_stage(seqs, "kernel_start", t0)
+        if not hedge:
+            self._stage(batch, self.clock(), "dispatched", "kernel_start")
+        fids = None
         attempts = 0
         while True:
             t0 = self.clock()
@@ -567,58 +578,156 @@ class Dispatcher:
                     fids = execute_batch(
                         batch, self.kernel, self.shift_kernel, self.multibank_kernel
                     )
+                if self.fault_injector is not None:
+                    # mirror the simulation's slowdown fault in wall time
+                    extra = (
+                        self.fault_injector.slowdown_factor(wid, t0) - 1.0
+                    ) * (self.clock() - t0)
+                    if extra > 0:
+                        time.sleep(extra)
+                err = None
                 break
-            except Exception as exc:
+            except BaseException as exc:
                 err = exc
             now = self.clock()
             tripped = self.fleet.on_failure(wid, now)
             tel.on_worker_failure(wid)
             if tripped:
                 tel.on_worker_offline(wid)
-                if tr.enabled:
-                    tr.batch_stage(seqs, "worker_offline", now, worker=wid)
+                self._stage(batch, now, "worker_offline", worker=wid)
             attempts += 1
-            if attempts <= self.ft.retry_limit and self.fleet.retryable(wid, now):
-                self.fleet.record_retry(wid)
-                tel.on_worker_retry(wid)
-                if tr.enabled:
-                    tr.batch_stage(seqs, "retried", now, worker=wid)
-                if self.ft.retry_backoff_s:
-                    time.sleep(self.ft.retry_backoff_s * 2 ** (attempts - 1))
-                continue
-            # out of retries: release the failed worker's capacity, then
-            # migrate through the coalescer if any surviving worker fits
-            self._charge(wid, -est)
-            self.manager.complete(wid, task, now)
-            self.fleet.on_release(wid)
+            if (
+                hedge
+                or attempts > self.ft.retry_limit
+                or not self.fleet.retryable(wid, now)
+            ):
+                break
+            self.fleet.record_retry(wid)
+            tel.on_worker_retry(wid)
+            self._stage(batch, now, "retried", worker=wid)
+            if self.ft.retry_backoff_s:
+                time.sleep(self.ft.retry_backoff_s * 2 ** (attempts - 1))
+        now = self.clock()
+        # settle against the (possibly hedged) runner set: the first
+        # successful runner claims the batch, the LAST failed runner with
+        # no winner owns migration or terminal failure.
+        with self._cv:
+            entry = self._runners.get(id(batch))
+            if entry is None:  # the batch's only runner
+                claimed, owner = err is None, err is not None
+            else:
+                entry["outstanding"] -= 1
+                claimed = err is None and entry["winner"] is None
+                if claimed:
+                    entry["winner"] = wid
+                last = entry["outstanding"] <= 0
+                owner = err is not None and last and entry["winner"] is None
+                if last:
+                    del self._runners[id(batch)]
+        failed = None
+        if claimed:
+            if tr.enabled:
+                tr.worker_span(wid, t0, now, args=kernel_span_args(batch))
+            self._observe(batch, now - t0)
+            self._record(batch)
+            self._record_spill(batch)
+            self.gateway.complete(batch, fids, now)
+        elif owner:
             bad = self.fleet.unplaceable(now)
-            survivors = [
-                w
-                for w, v in self.manager.workers.items()
-                if w != wid and w not in bad and v.max_qubits >= task.demand
-            ]
-            if survivors:
+            with self._cv:
+                survivor = any(
+                    w != wid and w not in bad and v.max_qubits >= self._width(batch)
+                    for w, v in self.manager.workers.items()
+                )
+            if survivor:
                 self.fleet.record_migration(wid)
                 tel.on_worker_migration(wid)
-                if tr.enabled:
-                    tr.batch_stage(seqs, "migrated", now, worker=wid)
+                self._stage(batch, now, "migrated", worker=wid)
                 self.gateway.requeue(batch, now)
-                return wid
+            else:
+                failed = err
+                self.gateway.fail(batch, err, now)
+        if err is None:
+            self.fleet.on_success(wid)
+        # futures are resolved BEFORE the worker is released, so a driver
+        # that counts releases knows every released batch's futures landed.
+        with self._cv:
+            if task is not None:
+                self.manager.complete(wid, task, now)
+            self._charge(wid, -est)
+            self.fleet.on_release(wid)
+            if claimed or owner:
+                self.batch_log.append((wid, batch.n, tuple(sorted(batch.clients()))))
+            self._release(wid, failed)
+        return failed
+
+    def _execute_spilled(self, batch: CoalescedBatch) -> BaseException | None:
+        """Execute one oversized batch on the whole device mesh (no single
+        worker is charged — the spill path is its own resource) and resolve
+        its futures.  Returns the error that failed them, if any."""
+        tel = self.gateway.telemetry
+        t0 = self.clock()
+        self._stage(batch, t0, "dispatched", "kernel_start")
+        err = fids = None
+        try:
+            with span("dispatcher.execute", tel.trace):
+                fids = execute_batch(batch, *self._spill_fns())
+        except BaseException as exc:
+            err = exc
+        now = self.clock()
+        if err is None:
+            if tel.trace.enabled:
+                tel.trace.worker_span(
+                    "mesh", t0, now, kind="spill", args=kernel_span_args(batch)
+                )
+            tel.service.update(
+                ("spill", batch_family(batch)), batch_cost_units(batch), now - t0
+            )
+            tel.on_spill(batch.lane_count)
+            self._record(batch)
+            self.gateway.complete(batch, fids, now)
+        else:
+            self.gateway.fail(batch, err, now)
+        with self._cv:
+            self.batch_log.append(("mesh", batch.n, tuple(sorted(batch.clients()))))
+            self._release(None, err)
+        return err
+
+    def _release(self, wid: str | None, err: BaseException | None) -> None:
+        """Driver hook, called under ``_cv`` once a batch has settled on
+        worker ``wid`` (None: the mesh); ``err`` failed its futures."""
+
+    # ---------------------------------------------------------- sync driver
+    def run_spilled(self, batch: CoalescedBatch) -> str:
+        """Execute one oversized batch on the whole device mesh, inline."""
+        self._stage(batch, self.clock(), "placed", worker="mesh")
+        err = self._execute_spilled(batch)
+        if err is not None:
+            raise err
+        return "mesh"
+
+    def run_batch(self, batch: CoalescedBatch) -> str:
+        """Place one batch via Algorithm 2 and execute it inline (retrying,
+        then migrating through the gateway's requeue on failure).  Raises
+        the error that failed the batch's futures."""
+        if self.mesh_spill and self._oversized(batch):
+            return self.run_spilled(batch)
+        now = self.clock()
+        placed = self._place(batch, now, self.fleet.unplaceable(now))
+        if placed is None:
+            if self.mesh_spill:
+                return self.run_spilled(batch)
+            caps = [v.max_qubits for v in self.manager.workers.values()]
+            err = RuntimeError(
+                f"no worker fits a {self._width(batch)}-qubit batch "
+                f"(capacities: {caps})"
+            )
             self.gateway.fail(batch, err, now)
             raise err
-        t1 = self.clock()
-        if tr.enabled:
-            tr.worker_span(wid, t0, t1, args=kernel_span_args(batch))
-        self._observe(batch, t1 - t0)
-        self._record(batch)
-        self._record_spill(batch)
-        self._charge(wid, -est)
-        self.manager.complete(wid, task, self.clock())
-        self.fleet.on_success(wid)
-        self.fleet.on_release(wid)
-        self.gateway.complete(batch, fids, self.clock())
-        self.batch_log.append((wid, batch.n, tuple(sorted(batch.clients()))))
-        return wid
+        err = self._execute(batch, *placed)
+        if err is not None:
+            raise err
+        return placed[1]
 
     # ---------------------------------------------------------------- pump
     def pump(self) -> int:
@@ -761,6 +870,31 @@ class GatewayRuntime:
     def __exit__(self, *exc) -> None:
         self.close()
 
+    def _submit_all(self, client_id: str, key, payloads, lanes: int) -> list:
+        """Admit every payload under ``key``, wake the dispatcher after each,
+        drain, and return the values in submission order."""
+        futures = []
+        for payload in payloads:
+            while True:
+                try:
+                    futures.append(
+                        self.gateway.submit(
+                            client_id,
+                            key,
+                            payload,
+                            now=self.dispatcher.clock(),
+                            lanes=lanes,
+                        )
+                    )
+                    break
+                except Backpressure:
+                    # sync: drain in-flight work; async: wait for a
+                    # completion to free queue space without quiescing
+                    self.dispatcher.absorb_backpressure()
+            self.dispatcher.kick()
+        self.dispatcher.drain()
+        return [f.value for f in futures]
+
     def executor(
         self,
         spec: CircuitSpec,
@@ -783,26 +917,8 @@ class GatewayRuntime:
             )
 
         def run(theta_bank: jnp.ndarray, data_bank: jnp.ndarray) -> jnp.ndarray:
-            futures = []
-            for i in range(theta_bank.shape[0]):
-                while True:
-                    try:
-                        futures.append(
-                            self.gateway.submit(
-                                client_id,
-                                spec,
-                                (theta_bank[i], data_bank[i]),
-                                now=self.dispatcher.clock(),
-                            )
-                        )
-                        break
-                    except Backpressure:
-                        # sync: drain in-flight work; async: wait for a
-                        # completion to free queue space without quiescing
-                        self.dispatcher.absorb_backpressure()
-                self.dispatcher.kick()
-            self.dispatcher.drain()
-            return jnp.stack([f.value for f in futures])
+            rows = ((theta_bank[i], data_bank[i]) for i in range(theta_bank.shape[0]))
+            return jnp.stack(self._submit_all(client_id, spec, rows, 1))
 
         return run
 
@@ -835,25 +951,10 @@ class GatewayRuntime:
         def run(bank, data_bank=None) -> jnp.ndarray:
             if data_bank is not None:
                 return row_run(bank, data_bank)
+            groups = ((bank, g) for g in range(bank.n_groups))
             key = ShiftGroupKey(spec, bank.four_term)
-            futures = []
-            for g in range(bank.n_groups):
-                while True:
-                    try:
-                        futures.append(
-                            self.gateway.submit(
-                                client_id,
-                                key,
-                                (bank, g),
-                                now=self.dispatcher.clock(),
-                                lanes=bank.n_samples,
-                            )
-                        )
-                        break
-                    except Backpressure:
-                        self.dispatcher.absorb_backpressure()
-                self.dispatcher.kick()
-            self.dispatcher.drain()
-            return jnp.concatenate([f.value for f in futures])
+            return jnp.concatenate(
+                self._submit_all(client_id, key, groups, bank.n_samples)
+            )
 
         return declare(run, shiftbank=True)

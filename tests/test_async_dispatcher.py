@@ -468,6 +468,53 @@ def test_oversized_batch_spills_to_mesh(specs):
         rt.close()
 
 
+class _BrokenMesh:
+    """A whole-mesh spill executor whose every launch raises."""
+
+    def rows(self, spec, theta, data):
+        raise RuntimeError("mesh launch failed")
+
+    def banks(self, spec, thetas, datas, four_term, group_sets):
+        raise RuntimeError("mesh launch failed")
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_spill_failure_fails_futures(specs, mode):
+    """A whole-mesh launch that raises resolves every future of the
+    spilled batch with its error, in both drivers; none is left hanging.
+    The sync driver also raises it from drain()."""
+    _, cfg7 = specs
+    rt = GatewayRuntime(
+        workers=[WorkerConfig("w1", 5)],
+        target=4,
+        lanes=4,
+        deadline=0.01,
+        mode=mode,
+        spill_executor=_BrokenMesh(),
+    )
+    try:
+        theta, data = rows_for(cfg7, 3)
+        futs = [
+            rt.gateway.submit(
+                "c", cfg7.spec, (theta[i], data[i]), rt.dispatcher.clock()
+            )
+            for i in range(3)
+        ]
+        if mode == "sync":
+            with pytest.raises(RuntimeError, match="mesh launch failed"):
+                rt.dispatcher.drain()
+        else:
+            rt.dispatcher.drain()
+            assert any("mesh launch failed" in str(e) for e in rt.dispatcher.errors)
+        for f in futs:
+            with pytest.raises(RuntimeError, match="mesh launch failed"):
+                f.result(timeout=10.0)
+        log = rt.dispatcher.batch_log
+        assert log and all(wid == "mesh" for wid, _, _ in log)
+    finally:
+        rt.close()
+
+
 def test_oversized_batch_fails_fast_when_spill_disabled(specs):
     """mesh_spill=False restores the strict contract: futures resolve with
     the placement error instead of deadlocking the pump."""

@@ -253,10 +253,11 @@ def test_crash_migration_is_bit_identical(specs, mode):
         rt.close()
 
 
-def test_sync_terminal_failure_fails_futures(specs):
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_terminal_failure_fails_futures(specs, mode):
     """Both workers crashed: no survivor to migrate to — the batch's
-    futures must resolve with the error (not hang) and the error must
-    propagate from run_batch."""
+    futures must resolve with the error (not hang).  The sync driver
+    raises it from drain(); the async driver puts it in its error ring."""
     cfg5, _ = specs
     ft = FaultToleranceConfig(retry_limit=0, breaker_threshold=1)
     inj = FaultInjector(
@@ -270,7 +271,7 @@ def test_sync_terminal_failure_fails_futures(specs):
         target=8,
         lanes=8,
         deadline=0.05,
-        mode="sync",
+        mode=mode,
         fault_tolerance=ft,
         fault_injector=inj,
     )
@@ -281,9 +282,15 @@ def test_sync_terminal_failure_fails_futures(specs):
             rt.gateway.submit("alice", cfg5.spec, (theta[i], data[i]), now())
             for i in range(8)
         ]
-        with pytest.raises(InjectedWorkerFault):
+        if mode == "sync":
+            with pytest.raises(InjectedWorkerFault):
+                rt.dispatcher.drain()
+            assert all(f.done for f in futs)
+        else:
             rt.dispatcher.drain()
-        assert all(f.done for f in futs)
+            assert any(
+                isinstance(e, InjectedWorkerFault) for e in rt.dispatcher.errors
+            )
         for f in futs:
             with pytest.raises(InjectedWorkerFault):
                 f.result(timeout=1.0)
